@@ -231,7 +231,6 @@ import time
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.maxeva_matmul import XYZConfig, shard_weight_xyz, xyz_matmul
 from repro.core.perf_model import collective_overlap_savings
-from repro.core.sharding import use_mesh
 from repro.launch.mesh import make_mesh
 
 mesh = make_mesh(2, 4)
@@ -266,7 +265,7 @@ def bench(m, k, n, y):
                 lambda xx, cfg=cfg: xyz_matmul(xx, w_xyz, mesh=mesh,
                                                cfg=cfg))
     outs, gouts = {}, {}
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         for name, f in fns.items():
             outs[name] = np.asarray(f(x))   # compile + warm
         for name, f in gfns.items():
@@ -313,7 +312,7 @@ def ring_overlap_rows():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"  # fake-device CPU mesh, never the chip
     r = subprocess.run([sys.executable, "-c", _RING_SUBPROC],
                        capture_output=True, text=True, timeout=1800,
                        env=env)

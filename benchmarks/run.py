@@ -10,6 +10,8 @@ import sys
 
 def main() -> None:
     sys.path.insert(0, "src")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import table1_kernels, table23_array, fig8_sizes, \
         tpu_matmul, roofline_report, fused_epilogue, int8_decode
 

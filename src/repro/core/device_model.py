@@ -71,18 +71,26 @@ class TPUDevice:
     hbm_bytes: int = 16 * 2 ** 30   # 16 GiB per chip
     ici_bw_per_link: float = 50e9   # bytes/s, per direction, per link
     ici_links: int = 4              # 2D torus: +/-x, +/-y
-    vmem_bytes: int = 16 * 2 ** 20  # ~16 MiB of VMEM per core
+    # Scoped VMEM one kernel may use: every pallas_call is compiled with
+    # this as its ``vmem_limit_bytes`` (the compiler's v5e default), so
+    # the planner's budget and the compiler's limit are the same number.
+    vmem_bytes: int = 16 * 2 ** 20
     # Fraction of VMEM the planner lets one kernel's working set claim
     # (compiler scratch, semaphores, pipelining headroom take the rest).
     vmem_budget_frac: float = 0.75
-    # MXU native tile granularity: the systolic array is 128x128; the
-    # minimal fp32/bf16 tile is (8, 128).
+    # MXU native tile granularity: the systolic array is 128x128; a VMEM
+    # tile is (8, 128) 32-bit words, so narrower dtypes pack more rows
+    # per tile: (16, 128) bf16, (32, 128) int8.
     mxu_dim: int = 128
     sublane: int = 8
 
     @property
     def vmem_budget(self) -> int:
         return int(self.vmem_bytes * self.vmem_budget_frac)
+
+    def row_tile(self, itemsize: int) -> int:
+        """Rows of one native VMEM tile for elements of ``itemsize``."""
+        return self.sublane * max(1, 4 // itemsize)
 
     def ridge_flops_per_byte(self, dtype: str = "bf16") -> float:
         """Arithmetic-intensity ridge point of the HBM roofline."""

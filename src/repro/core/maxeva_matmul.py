@@ -393,8 +393,8 @@ def _overlapped_gather_partial(x2: jnp.ndarray, wl: jnp.ndarray, axis: str,
 
 
 def _shard_map(body, mesh, in_specs, out_specs):
-    from repro.core.sharding import shard_map_compat
-    return shard_map_compat(body, mesh, in_specs, out_specs)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _check_epilogue_operands(ep: Optional[Epilogue], bias, residual,

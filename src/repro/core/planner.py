@@ -373,6 +373,53 @@ class MatmulPlan:
     shard: XYZShardPlan
 
 
+def _tile_bytes(rows: int, cols: int, itemsize: int,
+                device: TPUDevice) -> int:
+    """VMEM bytes of a ``(rows, cols)`` block: the layout pads rows to the
+    dtype's native tile height and columns to the 128-lane width."""
+    r = -(-rows // device.row_tile(itemsize)) * device.row_tile(itemsize)
+    c = -(-cols // 128) * 128
+    return r * c * itemsize
+
+
+def block_vmem_bytes(bm: int, bk: int, bn: int, dtype: str = "bf16",
+                     device: TPUDevice = TPU_V5E, accum_bytes: int = 4,
+                     epilogue=None) -> int:
+    """VMEM working set of one ``matmul_pallas`` program with block
+    ``(bm, bk, bn)``: the pipeline double-buffers every blocked operand
+    and output, the accumulator tile is a single scratch, and a
+    non-trivial store phase holds fp32 temporaries of the tile.
+
+    Epilogue tensor operands (residual, gate operand) are counted at
+    4 bytes, the widest dtype they arrive in.  int8 inputs always carry
+    their row/column scales into the store phase."""
+    ebytes = DTYPE_BYTES[dtype]
+    ep = epilogue
+    scaled = dtype == "int8"
+    row_vec = _tile_bytes(bm, 1, 4, device)      # [bm, 1] f32 column
+    col_vec = _tile_bytes(1, bn, 4, device)      # [1, bn] f32 row
+    tile32 = _tile_bytes(bm, bn, 4, device)
+    buffered = (_tile_bytes(bm, bk, ebytes, device)
+                + _tile_bytes(bk, bn, ebytes, device))
+    if scaled:
+        buffered += row_vec + col_vec
+    if ep is not None:
+        buffered += col_vec * (int(ep.bias) + int(ep.norm != "none"))
+        buffered += tile32 * (int(ep.residual) + int(ep.gate != "none"))
+        out_bytes = ep.out_itemsize()
+    else:
+        out_bytes = accum_bytes
+    out = _tile_bytes(bm, bn, out_bytes, device)
+    if ep is not None and ep.quantize:
+        out += row_vec
+    elif ep is not None and ep.norm != "none":
+        out *= 2
+    buffered += out
+    temps = 2 * tile32 if (scaled or (ep is not None
+                                      and not ep.is_identity)) else 0
+    return 2 * buffered + bm * bn * accum_bytes + temps
+
+
 def plan_tpu_block(
     m: int,
     k: int,
@@ -385,14 +432,17 @@ def plan_tpu_block(
     """Single-kernel level on TPU: pick the Pallas block (bm, bk, bn).
 
     The constraint structure mirrors eq. 1-6:
-      * eq. 1 (efficiency bound)  -> MXU alignment: bm, bn, bk multiples of
-        the systolic tile so the MXU runs at rated throughput;
+      * eq. 1 (efficiency bound)  -> MXU alignment: bn, bk multiples of
+        the systolic tile and bm a multiple of the dtype's native tile
+        height, so the MXU runs at rated throughput;
       * eq. 2 (I/O bound)         -> HBM-bandwidth bound: streaming the A
         and B blocks must not take longer than the MXU needs for the block,
         i.e. bn, bm >= peak_flops * sizeof / (2 * hbm_bw)  (the roofline
         ridge point expressed per-dimension);
-      * eq. 6 (local memory)      -> double-buffered A, B blocks plus the
-        fp32 accumulator tile fit the VMEM budget.
+      * eq. 6 (local memory)      -> the whole pipelined working set
+        (``block_vmem_bytes``: double-buffered operands, epilogue
+        operands and outputs, accumulator, store-phase temporaries)
+        fits the VMEM budget.
     Objective identical to the paper: maximize MACs per block (data reuse
     in registers/VMEM), tie-break to the squarest block.
     """
@@ -400,25 +450,25 @@ def plan_tpu_block(
     flops = device.peak_flops[dtype]
     # ridge-point lower bound (eq. 2 analog). 240 for bf16 on v5e.
     io_min = flops * ebytes / (2.0 * device.hbm_bw)
-
-    def align_floor(v: int, a: int) -> int:
-        return max(a, (v // a) * a)
+    row_tile = device.row_tile(ebytes)
 
     best: Optional[TPUBlockPlan] = None
     dim_cap = 4096
-    full_row = epilogue is not None and epilogue.quantize
+    full_row = epilogue is not None and (
+        (epilogue.quantize and epilogue.quantize_axis == "row")
+        or epilogue.norm != "none")
     if full_row:
-        # rowwise scale needs the whole row in one block: the kernel runs
-        # with bn = ceil128(n) regardless of the plan, so plan (and
-        # account VMEM for) exactly that block.  With the block covering
-        # all of N, A-reuse is maximal and eq. 2's bm bound is waived —
-        # the fp32 accumulator row dominates VMEM instead.
+        # rowwise scale / rmsnorm needs the whole row in one block: the
+        # kernel runs with bn = ceil128(n) regardless of the plan, so plan
+        # (and account VMEM for) exactly that block.  With the block
+        # covering all of N, A-reuse is maximal and eq. 2's bm bound is
+        # waived — the fp32 row tiles dominate VMEM instead.
         bn_candidates = [max(device.mxu_dim, 128 * ((n + 127) // 128))]
     else:
         bn_candidates = [bn for bn in _pow2_range(device.mxu_dim, dim_cap)
                          if bn <= max(n, device.mxu_dim) * 2]
-    for bm in _pow2_range(device.sublane, dim_cap):
-        if bm > max(m, device.sublane) * 2:
+    for bm in _pow2_range(row_tile, dim_cap):
+        if bm > max(m, row_tile) * 2:
             continue
         for bn in bn_candidates:
             for bk in _pow2_range(device.mxu_dim, dim_cap):
@@ -429,16 +479,9 @@ def plan_tpu_block(
                 if bn < min(io_min, n) or (not full_row
                                            and bm < min(io_min, m)):
                     continue
-                # eq. 6 analog: double-buffered in-blocks + accumulator.
-                vmem = 2 * (bm * bk + bk * bn) * ebytes + bm * bn * accum_bytes
-                if epilogue is not None:
-                    # fused-epilogue operands share the pipeline: a bias
-                    # row and/or a double-buffered residual tile join the
-                    # working set (the store phase reads them in VMEM).
-                    if epilogue.bias:
-                        vmem += bn * 4
-                    if epilogue.residual:
-                        vmem += 2 * bm * bn * ebytes
+                # eq. 6 analog: the pipelined working set.
+                vmem = block_vmem_bytes(bm, bk, bn, dtype, device,
+                                        accum_bytes, epilogue)
                 if vmem > device.vmem_budget:
                     continue
                 macs = bm * bk * bn

@@ -1,69 +1,10 @@
-"""Sharding helpers: logical axes -> PartitionSpec, mesh utilities.
-
-Also hosts the JAX version-compatibility shims (``make_mesh_compat``,
-``use_mesh``, ``shard_map_compat``): the codebase targets the current
-mesh/shard_map APIs (``jax.sharding.AxisType``, ``jax.set_mesh``,
-``jax.shard_map``) but must run on older installs where those live under
-different names (``jax.experimental.shard_map``, mesh-as-context-manager)
-or do not exist at all.
-"""
+"""Sharding helpers: logical axes -> PartitionSpec, mesh utilities."""
 from __future__ import annotations
 
-import contextlib
-from typing import Dict, Optional, Sequence, Tuple, Union
+from typing import Dict, Tuple
 
 import jax
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-# ---------------------------------------------------------------------------
-# version-compat shims
-# ---------------------------------------------------------------------------
-
-def make_mesh_compat(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types where the API supports them.
-
-    Auto types are required when mixing GSPMD-constrained jit code with
-    explicit shard_map blocks (the XYZ matmul) on new JAX; older versions
-    have no axis types and every axis is implicitly Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-            )
-        except TypeError:
-            pass  # make_mesh predates the axis_types kwarg
-    return jax.make_mesh(shape, axes)
-
-
-def use_mesh(mesh: Mesh):
-    """Context manager installing ``mesh`` as the ambient mesh:
-    ``jax.set_mesh`` on new JAX, ``jax.sharding.use_mesh`` on mid-vintage,
-    and the mesh's own context manager on old installs."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    um = getattr(jax.sharding, "use_mesh", None)
-    if um is not None:
-        return um(mesh)
-    return mesh  # Mesh is itself a context manager
-
-
-def shard_map_compat(body, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across API generations: new JAX spells the
-    replication check ``check_vma``, older ``check_rep``, and oldest only
-    ships it as ``jax.experimental.shard_map.shard_map``."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:  # older spelling
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
 
 
 def mesh_axis_sizes(mesh: Mesh) -> Dict[str, int]:

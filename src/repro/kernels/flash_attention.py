@@ -44,6 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.device_model import TPU_V5E
+
 _NEG = -1e30
 
 # Default KV tile of the decode paths.  Both the dense and the paged
@@ -326,8 +328,6 @@ def flash_attention_pallas(q, k, v, *, kind="global", window=0,
         _prefill_kernel, n_k=n_k, kind=kind, window=window,
         prefix_len=prefix_len, softcap=softcap, q_offset=q_offset,
         kv_len=skv, scale=float(hd) ** -0.5, block_q=bq, block_k=bk)
-    cp_cls = (getattr(pltpu, "CompilerParams", None)
-              or getattr(pltpu, "TPUCompilerParams", None))
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -343,8 +343,9 @@ def flash_attention_pallas(q, k, v, *, kind="global", window=0,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, hd_p), jnp.float32),
         ],
-        compiler_params=cp_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=TPU_V5E.vmem_bytes),
         interpret=interpret,
     )(qr, kr, vr)
     out = out[:, :sq, :hd].reshape(b, n_h, sq, hd)
@@ -384,8 +385,8 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *,
         l_t = jnp.sum(p, axis=-1)
         acc_t = jax.lax.dot_general(p, v_t, (((1,), (0,)), ((), ())),
                                     preferred_element_type=jnp.float32)
-        m_ref[0, tt] = m_t
-        l_ref[0, tt] = l_t
+        m_ref[0, tt] = m_t[None]
+        l_ref[0, tt] = l_t[None]
         acc_ref[0, tt] = acc_t
 
 
@@ -423,8 +424,6 @@ def flash_decode_pallas(q, k_cache, v_cache, pos, *, kind="global",
         _decode_kernel, tiles_per_split=tiles_per_split, kv_tile=kv_tile,
         kv_len=kv_len, kind=kind, softcap=softcap,
         scale=float(hd) ** -0.5, g_p=g_p)
-    cp_cls = (getattr(pltpu, "CompilerParams", None)
-              or getattr(pltpu, "TPUCompilerParams", None))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b * n_kv, n_splits),
@@ -435,11 +434,13 @@ def flash_decode_pallas(q, k_cache, v_cache, pos, *, kind="global",
             pl.BlockSpec((1, split_len, hd_p),
                          lambda r, s, pos_ref: (r, s, 0)),
         ],
+        # m/l carry a unit axis so each block's trailing dims are whole
+        # array dims (the TPU block-shape rule) for any split count
         out_specs=[
-            pl.BlockSpec((1, tiles_per_split, g_p),
-                         lambda r, s, pos_ref: (r, s, 0)),
-            pl.BlockSpec((1, tiles_per_split, g_p),
-                         lambda r, s, pos_ref: (r, s, 0)),
+            pl.BlockSpec((1, tiles_per_split, 1, g_p),
+                         lambda r, s, pos_ref: (r, s, 0, 0)),
+            pl.BlockSpec((1, tiles_per_split, 1, g_p),
+                         lambda r, s, pos_ref: (r, s, 0, 0)),
             pl.BlockSpec((1, tiles_per_split, g_p, hd_p),
                          lambda r, s, pos_ref: (r, s, 0, 0)),
         ],
@@ -448,18 +449,19 @@ def flash_decode_pallas(q, k_cache, v_cache, pos, *, kind="global",
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b * n_kv, n_tiles, g_p), jnp.float32),
-            jax.ShapeDtypeStruct((b * n_kv, n_tiles, g_p), jnp.float32),
+            jax.ShapeDtypeStruct((b * n_kv, n_tiles, 1, g_p), jnp.float32),
+            jax.ShapeDtypeStruct((b * n_kv, n_tiles, 1, g_p), jnp.float32),
             jax.ShapeDtypeStruct((b * n_kv, n_tiles, g_p, hd_p),
                                  jnp.float32),
         ],
-        compiler_params=cp_cls(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=TPU_V5E.vmem_bytes),
         interpret=interpret,
     )(pos_arr, qr, kr, vr)
     # tiles to axis 0, then the shared deterministic combine
-    out = combine_tile_partials(jnp.moveaxis(m_t, 1, 0),
-                                jnp.moveaxis(l_t, 1, 0),
+    out = combine_tile_partials(jnp.moveaxis(m_t[:, :, 0], 1, 0),
+                                jnp.moveaxis(l_t[:, :, 0], 1, 0),
                                 jnp.moveaxis(acc_t, 1, 0))
     out = out.reshape(b, n_kv, g_p, hd_p)[:, :, :g, :hd]
     return out[:, None].astype(q.dtype)
@@ -500,8 +502,8 @@ def _paged_decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref,
     l_t = jnp.sum(p, axis=-1)
     acc_t = jax.lax.dot_general(p, v_t, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-    m_ref[0, 0, 0] = m_t
-    l_ref[0, 0, 0] = l_t
+    m_ref[0, 0, 0] = m_t[None]
+    l_ref[0, 0, 0] = l_t[None]
     acc_ref[0, 0, 0] = acc_t
 
 
@@ -540,8 +542,6 @@ def paged_flash_decode_pallas(q, k_pool, v_pool, page_table, positions, *,
     kernel = functools.partial(
         _paged_decode_kernel, ps=ps, kind=kind, window=window,
         softcap=softcap, scale=float(hd) ** -0.5, g_p=g_p, n_kv=n_kv)
-    cp_cls = (getattr(pltpu, "CompilerParams", None)
-              or getattr(pltpu, "TPUCompilerParams", None))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, n_kv, p_max),
@@ -551,11 +551,13 @@ def paged_flash_decode_pallas(q, k_pool, v_pool, page_table, positions, *,
             pl.BlockSpec((1, ps, hd_p), pool_row),
             pl.BlockSpec((1, ps, hd_p), pool_row),
         ],
+        # m/l carry a unit axis so each block's trailing dims are whole
+        # array dims (the TPU block-shape rule)
         out_specs=[
-            pl.BlockSpec((1, 1, 1, g_p),
-                         lambda lane, h, page, t, p: (page, lane, h, 0)),
-            pl.BlockSpec((1, 1, 1, g_p),
-                         lambda lane, h, page, t, p: (page, lane, h, 0)),
+            pl.BlockSpec((1, 1, 1, 1, g_p),
+                         lambda lane, h, page, t, p: (page, lane, h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1, g_p),
+                         lambda lane, h, page, t, p: (page, lane, h, 0, 0)),
             pl.BlockSpec((1, 1, 1, g_p, hd_p),
                          lambda lane, h, page, t, p: (page, lane, h, 0, 0)),
         ],
@@ -564,14 +566,16 @@ def paged_flash_decode_pallas(q, k_pool, v_pool, page_table, positions, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((p_max, b, n_kv, g_p), jnp.float32),
-            jax.ShapeDtypeStruct((p_max, b, n_kv, g_p), jnp.float32),
+            jax.ShapeDtypeStruct((p_max, b, n_kv, 1, g_p), jnp.float32),
+            jax.ShapeDtypeStruct((p_max, b, n_kv, 1, g_p), jnp.float32),
             jax.ShapeDtypeStruct((p_max, b, n_kv, g_p, hd_p), jnp.float32),
         ],
-        compiler_params=cp_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=TPU_V5E.vmem_bytes),
         interpret=interpret,
     )(table, pos, qr, kr, vr)
-    out = combine_tile_partials(m_t, l_t, acc_t)     # [B, KV, g_p, hd_p]
+    out = combine_tile_partials(m_t[..., 0, :], l_t[..., 0, :],
+                                acc_t)               # [B, KV, g_p, hd_p]
     out = out[:, :, :g, :hd]
     return out[:, None].astype(q.dtype)

@@ -48,6 +48,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.device_model import TPU_V5E
 from repro.kernels.epilogue import Epilogue, apply_epilogue
 from repro.kernels.ref import accum_dtype
 
@@ -253,14 +254,6 @@ def matmul_pallas(
         has_norm_scale=ep.norm != "none",
         norm_n=n if ep.norm != "none" else None,
     )
-    params = {}
-    cp_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cp_cls is not None:
-        params["compiler_params"] = cp_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
     cost = None
     if cost_hint:
         # the fused path stores the finished epilogue output ONCE; the
@@ -299,7 +292,9 @@ def matmul_pallas(
         scratch_shapes=[pltpu.VMEM((bm, bn), acc)],
         interpret=interpret,
         cost_estimate=cost,
-        **params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=TPU_V5E.vmem_bytes),
     )(*operands)
     if ep.quantize:
         q, s = out

@@ -9,6 +9,7 @@ a backend flag through every layer.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 from typing import Optional, Tuple
@@ -16,6 +17,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.device_model import TPU_V5E
 from repro.core.planner import plan_tpu_block
 from repro.kernels import ref
 from repro.kernels.epilogue import Epilogue
@@ -63,20 +65,55 @@ def planner_dtype_key(dtype) -> str:
 
 
 @functools.lru_cache(maxsize=None)
-def default_block(m: int, k: int, n: int, dtype: str) -> Tuple[int, int, int]:
-    b = plan_tpu_block(m, k, n, planner_dtype_key(dtype))
+def default_block(m: int, k: int, n: int, dtype: str,
+                  epilogue: Optional[Epilogue] = None
+                  ) -> Tuple[int, int, int]:
+    b = plan_tpu_block(m, k, n, planner_dtype_key(dtype),
+                       epilogue=epilogue)
     return (b.bm, b.bk, b.bn)
 
 
-def _clamped_default_block(m: int, k: int, n: int,
-                           dtype: str) -> Tuple[int, int, int]:
-    """Planned block, never exceeding the (padded) problem itself."""
-    block = default_block(m, k, n, dtype)
+def _clamped_default_block(m: int, k: int, n: int, dtype: str,
+                           epilogue: Optional[Epilogue] = None
+                           ) -> Tuple[int, int, int]:
+    """Planned block, never exceeding the (padded) problem itself; the
+    row block stays a whole native tile of the input dtype."""
+    block = default_block(m, k, n, dtype, epilogue)
+    rows = TPU_V5E.row_tile(jnp.dtype(dtype).itemsize)
     return (
-        min(block[0], _round_pow2_up(m)),
+        min(block[0], max(rows, _round_pow2_up(m))),
         min(block[1], _round_pow2_up(k)),
         min(block[2], _round_pow2_up(n)),
     )
+
+
+def _planned_epilogue(epilogue: Optional[Epilogue],
+                      out_dtype) -> Epilogue:
+    """The store phase the kernel will run: the spec with the call's
+    ``out_dtype`` filled in (what the planner sizes output tiles by)."""
+    ep = epilogue or Epilogue()
+    if out_dtype is not None and ep.out_dtype is None:
+        ep = dataclasses.replace(ep, out_dtype=out_dtype)
+    return ep
+
+
+def _with_reference_vjp(kernel, reference):
+    """``kernel`` forward, differentiated through ``reference`` — the XLA
+    mirror with the same semantics (Pallas calls have no transpose rule,
+    so a training step could not take their gradient otherwise).  The
+    backward pass recomputes the forward in XLA."""
+    @jax.custom_vjp
+    def f(*operands):
+        return kernel(*operands)
+
+    def fwd(*operands):
+        return kernel(*operands), operands
+
+    def bwd(operands, g):
+        return jax.vjp(reference, *operands)[1](g)
+
+    f.defvjp(fwd, bwd)
+    return f
 
 
 def matmul(
@@ -118,24 +155,31 @@ def matmul(
                            block=block, mode=mode, epilogue=epilogue,
                            bias=bias, residual=residual,
                            operand2=operand2, norm_scale=norm_scale)
-    if mode == "xla":
+    def reference(a, b, bias, residual, operand2, norm_scale):
         if epilogue is None:
             return ref.matmul_ref(a, b, out_dtype)
-        if out_dtype is not None and epilogue.out_dtype is None:
-            # honor the out_dtype argument exactly like the kernel path
-            import dataclasses
-            epilogue = dataclasses.replace(epilogue, out_dtype=out_dtype)
-        return ref.matmul_fused_ref(a, b, epilogue, bias=bias,
-                                    residual=residual, operand2=operand2,
+        # honor the out_dtype argument exactly like the kernel path
+        return ref.matmul_fused_ref(a, b,
+                                    _planned_epilogue(epilogue, out_dtype),
+                                    bias=bias, residual=residual,
+                                    operand2=operand2,
                                     norm_scale=norm_scale)
+
+    operands = (a, b, bias, residual, operand2, norm_scale)
+    if mode == "xla":
+        return reference(*operands)
     if block is None:
-        block = _clamped_default_block(a.shape[0], a.shape[1], b.shape[1],
-                                       str(a.dtype))
-    return matmul_pallas(
-        a, b, block=block, out_dtype=out_dtype,
-        interpret=(mode == "interpret"), epilogue=epilogue, bias=bias,
-        residual=residual, operand2=operand2, norm_scale=norm_scale,
-    )
+        block = _clamped_default_block(
+            a.shape[0], a.shape[1], b.shape[1], str(a.dtype),
+            _planned_epilogue(epilogue, out_dtype))
+
+    def kernel(a, b, bias, residual, operand2, norm_scale):
+        return matmul_pallas(
+            a, b, block=block, out_dtype=out_dtype,
+            interpret=(mode == "interpret"), epilogue=epilogue, bias=bias,
+            residual=residual, operand2=operand2, norm_scale=norm_scale)
+
+    return _with_reference_vjp(kernel, reference)(*operands)
 
 
 def int8_matmul(
@@ -174,16 +218,14 @@ def int8_matmul(
         "a residual operand requires Epilogue(residual=True)")
     assert ep.gate != "none" or operand2 is None, (
         "an operand2 requires Epilogue(gate=...)")
-    if out_dtype is not None and ep.out_dtype is None:
-        import dataclasses
-        ep = dataclasses.replace(ep, out_dtype=out_dtype)
+    ep = _planned_epilogue(ep, out_dtype)
     if mode == "xla":
         return ref.int8_matmul_ref(qa, sa, qb, sb, ep, bias=bias,
                                    residual=residual, operand2=operand2,
                                    norm_scale=norm_scale)
     if block is None:
         block = _clamped_default_block(qa.shape[0], qa.shape[1],
-                                       qb.shape[1], "int8")
+                                       qb.shape[1], "int8", ep)
     return matmul_pallas(
         qa, qb, block=block, interpret=(mode == "interpret"), epilogue=ep,
         a_scale=sa, b_scale=sb, bias=bias, residual=residual,
@@ -265,10 +307,19 @@ def flash_attention(q, k, v, *, kind="global", window=0, prefix_len=0,
     from repro.kernels.flash_attention import flash_attention_pallas
     mode = mode or kernel_mode()
     assert mode in ("pallas", "interpret"), mode
-    return flash_attention_pallas(
-        q, k, v, kind=kind, window=window, prefix_len=prefix_len,
-        softcap=softcap, q_offset=q_offset, block_q=block_q,
-        block_k=block_k, interpret=(mode == "interpret"))
+
+    def kernel(q, k, v):
+        return flash_attention_pallas(
+            q, k, v, kind=kind, window=window, prefix_len=prefix_len,
+            softcap=softcap, q_offset=q_offset, block_q=block_q,
+            block_k=block_k, interpret=(mode == "interpret"))
+
+    def reference(q, k, v):
+        return ref.flash_attention_ref(
+            q, k, v, kind=kind, window=window, prefix_len=prefix_len,
+            softcap=softcap, q_offset=q_offset)
+
+    return _with_reference_vjp(kernel, reference)(q, k, v)
 
 
 def flash_decode(q, k_cache, v_cache, pos, *, kind="global", softcap=None,

@@ -15,6 +15,9 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.device_model import TPU_V5E
 
 
 @jax.tree_util.register_pytree_node_class
@@ -67,7 +70,9 @@ def quantize_weight_colwise(w: jnp.ndarray) -> QuantizedWeight:
     scale per output column, shared by every row of the contraction — the
     layout the int8 GEMM's store-phase epilogue folds back in."""
     from repro.kernels.ref import quantize_colwise_ref
-    q, s = quantize_colwise_ref(w)
+    # one fused program per weight: the eager op chain would hold several
+    # fp32 temporaries of the whole (layer-stacked) weight at once
+    q, s = jax.jit(quantize_colwise_ref)(w)
     return QuantizedWeight(q, s)
 
 
@@ -140,6 +145,9 @@ def quantize_rowwise_pallas(
             jax.ShapeDtypeStruct((mp, n), jnp.int8),
             jax.ShapeDtypeStruct((mp, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=TPU_V5E.vmem_bytes),
         interpret=interpret,
     )(xp)
     return q[:m], s[:m]

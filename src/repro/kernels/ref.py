@@ -7,6 +7,7 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 _ACCUM = {
@@ -14,6 +15,12 @@ _ACCUM = {
     jnp.dtype("bfloat16"): jnp.float32,
     jnp.dtype("float32"): jnp.float32,
 }
+
+
+def x64():
+    """Context manager enabling 64-bit types: the scope every f64 oracle
+    (the consistency budgets' precision reference) runs in."""
+    return jax.enable_x64(True)
 
 
 def accum_dtype(dtype) -> jnp.dtype:

@@ -65,7 +65,6 @@ def _selftest() -> int:
     # all-gather before the GEMM — the collective-schedule pass must
     # reject it against the overlapped plan's allowed set
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.launch.mesh import make_mesh
 
     mesh = make_mesh(2, 4)
@@ -80,10 +79,10 @@ def _selftest() -> int:
             partial, "model", scatter_dimension=1,
             axis_index_groups=[[0, 1], [2, 3]], tiled=True)
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         barrier_body, mesh=mesh,
         in_specs=(P("data", "model"), P("model", None)),
-        out_specs=P("data", "model")))
+        out_specs=P("data", "model"), check_vma=False))
     hlo = fn.lower(
         jax.ShapeDtypeStruct((8, 32), jnp.float32),
         jax.ShapeDtypeStruct((64, 16), jnp.float32)).compile().as_text()

@@ -15,7 +15,6 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import ARCH_IDS, SHAPES, get_config, runnable_cells  # noqa: E402
-from repro.core.sharding import use_mesh  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.roofline import collective_wire_bytes, roofline_terms  # noqa: E402
 from repro.launch.specs import input_specs, param_io_specs  # noqa: E402
@@ -82,7 +81,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
     model = Model(cfg, mesh)
     opt_cfg = AdamWConfig(state_mode=cfg.opt_state_mode)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         aparams, pspecs = param_io_specs(model)
         if cell.kind == "train":
             fn = make_train_step(model, opt_cfg)

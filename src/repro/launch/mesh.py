@@ -9,16 +9,14 @@ from __future__ import annotations
 from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-from repro.core.sharding import make_mesh_compat
+from jax.sharding import AxisType, Mesh
 
 
 def _mk(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
     # Auto axis types: the framework mixes GSPMD-constrained jit code with
     # explicit shard_map blocks (the XYZ matmul), which requires Auto.
-    # make_mesh_compat degrades gracefully on JAX without AxisType.
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

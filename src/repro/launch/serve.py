@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.lm import Model
 from repro.robust import FaultPlan, LogitFault, StallFault, generate_with_retry
@@ -82,6 +83,7 @@ def main():
                     help="fail the first N generate() calls with a "
                          "retryable error (exercises the retry wrapper)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     mesh = make_mesh(jax.device_count(), 1)
     cfg = get_config(args.arch, smoke=args.smoke)

@@ -15,6 +15,7 @@ import jax
 
 from repro.configs import get_config
 from repro.data import DataConfig, SyntheticTokenSource, TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.lm import Model
 from repro.optim import AdamWConfig
@@ -38,6 +39,7 @@ def main():
     ap.add_argument("--model-mesh", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")

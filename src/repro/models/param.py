@@ -21,6 +21,7 @@ bitwise identical to declaring the views as separate ParamDefs.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -113,7 +114,20 @@ def _path_seed(path: str, base: int) -> int:
     return int.from_bytes(h[:4], "little")
 
 
-def _init_one(d: ParamDef, path: str, base_seed: int) -> jax.Array:
+def _init_one(d: ParamDef, path: str, base_seed: int,
+              sharding: Optional[NamedSharding] = None) -> jax.Array:
+    """Materialize one leaf.  With ``sharding`` the random draws are made
+    in place at that sharding (the partitionable RNG gives the same
+    values as an unsharded draw), so no device holds a whole large leaf
+    on the way."""
+    def place(arr):
+        return arr if sharding is None else jax.device_put(arr, sharding)
+
+    def draw(rng):
+        if sharding is None:
+            return rng(key)
+        return jax.jit(rng, out_shardings=sharding)(key)
+
     if d.views is not None:
         # per-view streams at <parent>/<view> (the packed def's own name is
         # replaced by the view name): bitwise identical to declaring the
@@ -121,28 +135,31 @@ def _init_one(d: ParamDef, path: str, base_seed: int) -> jax.Array:
         parent = path.rsplit("/", 1)[0]
         vs = {name: _init_one(vd, f"{parent}/{name}", base_seed)
               for name, vd in view_defs(d).items()}
-        return pack_views(d, vs)
+        return place(pack_views(d, vs))
     key = jax.random.PRNGKey(_path_seed(path, base_seed))
     dt = jnp.dtype(d.dtype)
     if d.init == "zeros":
-        return jnp.zeros(d.shape, dt)
+        return jnp.zeros(d.shape, dt, device=sharding)
     if d.init == "ones":
-        return jnp.ones(d.shape, dt)
+        return jnp.ones(d.shape, dt, device=sharding)
     if d.init == "lru_log":
         # RG-LRU Lambda init: uniform such that a = exp(-c*softplus(L)) has
         # moduli in [0.9, 0.999]
-        u = jax.random.uniform(key, d.shape, jnp.float32,
-                               minval=0.9 ** 2, maxval=0.999 ** 2)
+        u = draw(functools.partial(jax.random.uniform, shape=d.shape,
+                                   dtype=jnp.float32, minval=0.9 ** 2,
+                                   maxval=0.999 ** 2))
         lam = jnp.log(jnp.expm1(-0.5 * jnp.log(u) / 8.0))
         return lam.astype(dt)
     if d.init == "custom":
         # broadcast handles group-stacked defs (leading group axis added
         # after the custom fn was declared)
-        return jnp.broadcast_to(d.custom(key), d.shape).astype(dt)
+        return place(jnp.broadcast_to(d.custom(key), d.shape).astype(dt))
     # normal with fan-in scaling: fan_in = second-to-last dim by convention
     fan_in = d.shape[-2] if len(d.shape) >= 2 else d.shape[-1]
     scale = d.scale if d.scale is not None else 1.0 / np.sqrt(max(1, fan_in))
-    return (jax.random.normal(key, d.shape, jnp.float32) * scale).astype(dt)
+    return (draw(functools.partial(jax.random.normal, shape=d.shape,
+                                   dtype=jnp.float32))
+            * scale).astype(dt)
 
 
 def _walk(tree: Any, fn: Callable[[ParamDef, str], Any], path: str = "") -> Any:
@@ -168,10 +185,9 @@ def specs(tree: Any) -> Any:
 def initialize(tree: Any, seed: int = 0,
                mesh: Optional[Mesh] = None) -> Any:
     def mk(d: ParamDef, path: str):
-        arr = _init_one(d, path, seed)
-        if mesh is not None and mesh.devices.size > 1:
-            arr = jax.device_put(arr, NamedSharding(mesh, d.spec))
-        return arr
+        sharding = (NamedSharding(mesh, d.spec)
+                    if mesh is not None and mesh.devices.size > 1 else None)
+        return _init_one(d, path, seed, sharding)
     return _walk(tree, mk)
 
 

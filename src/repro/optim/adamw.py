@@ -64,7 +64,9 @@ def _decode(x, sqrt_scale: bool = False) -> jnp.ndarray:
 
 def init_opt_state(params: Any, cfg: AdamWConfig) -> Dict[str, Any]:
     def zeros_like_enc(p, sqrt_scale=False):
-        z = jnp.zeros(p.shape, jnp.float32)
+        # zeros_like keeps the parameter's sharding: sharded params get
+        # sharded moments, not a whole copy on the default device
+        z = jnp.zeros_like(p, dtype=jnp.float32)
         return _encode(z, cfg.state_mode, sqrt_scale)
 
     return {

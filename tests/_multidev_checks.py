@@ -27,7 +27,6 @@ from repro.core.maxeva_matmul import (  # noqa: E402
     xyz_matmul,
     xyz_matmul_replicated_out,
 )
-from repro.core.sharding import use_mesh  # noqa: E402
 
 
 def make_mesh():
@@ -84,18 +83,18 @@ def check_schedule_equivalence_epilogue():
     print("ok schedule_equivalence_epilogue")
 
 
-def check_replicated_out():
+def check_xyz_replicated_out():
     mesh = make_mesh()
     x, w = _data()
     want = np.asarray(jnp.einsum("bsk,kn->bsn", x, w))
     for layout in ("replicated", "ksharded"):
         cfg = XYZConfig(y=4, schedule="allreduce", x_layout=layout)
         w_xyz = shard_weight_xyz(w, 4, 4)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             got = xyz_matmul_replicated_out(x, w_xyz, mesh=mesh, cfg=cfg)
         np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5,
                                    atol=2e-5, err_msg=layout)
-    print("ok replicated_out")
+    print("ok xyz_replicated_out")
 
 
 def check_overlapped_gather_hlo():
@@ -109,7 +108,7 @@ def check_overlapped_gather_hlo():
         cfg = XYZConfig(y=2, schedule=sched, x_layout="ksharded")
         w_xyz = shard_weight_xyz(w, 4, 2)
         f = jax.jit(lambda xx: xyz_matmul(xx, w_xyz, mesh=mesh, cfg=cfg))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             txt = f.lower(x).compile().as_text()
         assert "all-gather" not in txt, f"{sched}: barrier all-gather of A"
         assert "collective-permute" in txt, sched
@@ -119,7 +118,7 @@ def check_overlapped_gather_hlo():
     cfg1 = XYZConfig(y=1, x_layout="ksharded")
     w1 = shard_weight_xyz(w, 4, 1)
     f1 = jax.jit(lambda xx: xyz_matmul(xx, w1, mesh=mesh, cfg=cfg1))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         txt1 = f1.lower(x).compile().as_text()
     assert "all-gather" in txt1
     print("ok overlapped_gather_hlo")
@@ -144,7 +143,7 @@ def check_xyz_epilogue():
         want = jax.nn.gelu(base + bias) + res
         cfg = XYZConfig(y=y, schedule=sched, epilogue=ep)
         w_xyz = shard_weight_xyz(w, 4, y)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             got = xyz_matmul(x, w_xyz, mesh=mesh, cfg=cfg, bias=bias,
                              residual=res)
         np.testing.assert_allclose(
@@ -155,7 +154,7 @@ def check_xyz_epilogue():
     epq = Epilogue(activation="silu", quantize=True)
     cfgq = XYZConfig(y=2, schedule="ring", epilogue=epq)
     w_xyz = shard_weight_xyz(w, 4, 2)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         q, s = xyz_matmul(x, w_xyz, mesh=mesh, cfg=cfgq)
     assert q.shape == base.shape and q.dtype == jnp.int8, (q.shape, q.dtype)
     assert s.shape == (*base.shape[:-1], 4) and s.dtype == jnp.float32
@@ -172,7 +171,7 @@ def check_xyz_epilogue():
     epr = Epilogue(bias=True, activation="relu")
     cfgr = XYZConfig(y=4, schedule="allreduce", epilogue=epr)
     w_xyz = shard_weight_xyz(w, 4, 4)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = xyz_matmul_replicated_out(x, w_xyz, mesh=mesh, cfg=cfgr,
                                         bias=bias)
     want = jax.nn.relu(base + bias)
@@ -204,7 +203,7 @@ def check_grads():
             return jnp.sum(jnp.sin(jnp.einsum("bsk,kn->bsn", xx,
                                               unshard_weight_xyz(ww, y))))
 
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             gx, gw = jax.grad(loss_sharded, argnums=(0, 1))(x, w_xyz)
         gx_r, gw_r = jax.grad(loss_ref, argnums=(0, 1))(x, w_xyz)
         np.testing.assert_allclose(np.asarray(gx), np.asarray(gx_r),
@@ -232,7 +231,7 @@ def check_grads():
             h = jnp.einsum("bsk,kn->bsn", xx, unshard_weight_xyz(ww, y))
             return jnp.sum(jnp.sin(jax.nn.gelu(h + bb)))
 
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             gx, gw, gb = jax.grad(loss_fused, argnums=(0, 1, 2))(
                 x, w_xyz, bias)
         gx_r, gw_r, gb_r = jax.grad(loss_unfused, argnums=(0, 1, 2))(
@@ -264,7 +263,7 @@ def check_packed_qkv_fused_sp():
         attn_defs(cfg, 4, "float32", False)["wqkv"], params["wqkv"])
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 16, cfg.d_model),
                           jnp.float32)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         q, k, v = fused_qkv_sp(params, x, cfg, ctx)
     b, s = x.shape[:2]
     for got, w, n in ((q, views["wq"], cfg.n_heads),
@@ -284,7 +283,7 @@ def check_packed_model_forward():
     mesh = make_mesh()
     cfg = get_config("internlm2-1.8b", smoke=True)
     model = Model(cfg, mesh)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init_params(0)
         k1, k2 = jax.random.split(jax.random.PRNGKey(0))
         batch = {"tokens": jax.random.randint(k1, (4, 32), 0, cfg.vocab,
@@ -317,7 +316,7 @@ def check_mlp_composition():
         h = jax.nn.gelu(h)
         return xyz_matmul(h, w2x, mesh=mesh, cfg=down)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = mlp(x)
     want = jnp.einsum("bsk,kn->bsn", jax.nn.gelu(jnp.einsum(
         "bsk,kn->bsn", x, w1)), w2)
@@ -341,7 +340,7 @@ def check_collective_bytes_ordering():
         cfg = XYZConfig(y=4, schedule=sched)
         w_xyz = shard_weight_xyz(w, 4, 4)
         f = jax.jit(lambda xx: xyz_matmul(xx, w_xyz, mesh=mesh, cfg=cfg))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             comp = f.lower(x).compile()
         return collective_wire_bytes(comp.as_text())["total_wire_bytes"]
 

@@ -42,7 +42,6 @@ from repro.core.maxeva_matmul import (  # noqa: E402
     shard_weight_xyz,
     xyz_matmul,
 )
-from repro.core.sharding import use_mesh  # noqa: E402
 from repro.kernels.epilogue import Epilogue  # noqa: E402
 
 MODEL = 4
@@ -135,7 +134,7 @@ def run_combo(mesh, *, y, layout, ep_name, schedules=None,
     outs = {}
     for sched in schedules:
         cfg = XYZConfig(y=y, schedule=sched, x_layout=layout, epilogue=ep)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             out = xyz_matmul(x, w_xyz, mesh=mesh, cfg=cfg, **kwargs)
         outs[sched] = [np.asarray(o) for o in _flat(out)]
 

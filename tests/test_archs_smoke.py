@@ -79,9 +79,9 @@ def f64_reference_logits(cfg, params, fbatch, mesh):
     still run their internal fp32 stages — the GEMM chain, where prefill
     vs decode rounding can diverge, is what runs at f64)."""
     import dataclasses as dc
-    from jax.experimental import enable_x64
+    from repro.kernels.ref import x64
     from repro.models.loss import vocab_parallel_logits
-    with enable_x64():
+    with x64():
         cfg64 = dc.replace(cfg, compute_dtype="float64",
                            param_dtype="float64")
         model64 = Model(cfg64, mesh)

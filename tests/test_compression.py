@@ -43,7 +43,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax, jax.numpy as jnp, numpy as np
 from repro.launch.mesh import make_mesh
-from repro.core.sharding import use_mesh
 from repro.optim import AdamWConfig
 from repro.optim.compression import init_error_state, make_dp_train_step
 
@@ -70,7 +69,7 @@ for mode in ("none", "int8_ef"):
     opt = init_opt_state(p, cfg)
     err = init_error_state(p, 8)
     step = make_dp_train_step(loss_fn, cfg, mesh, "data", mode)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         for s in range(150):
             loss, p, opt, err = step(p, opt, err, data(s))
     results[mode] = (float(loss), float(jnp.max(jnp.abs(p["w"] - w_true))))
@@ -90,7 +89,7 @@ for mode in ("none", "int8_ef"):
     err = init_error_state(p, 8)
     step = make_dp_train_step(loss_fn, cfg, mesh, "data", mode)
     b = {"x": jnp.zeros((64, 256)), "y": jnp.zeros((64, 256))}
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         txt = step.lower(p, opt, err, b).compile().as_text()
     an = analyze_hlo(txt)
     outs[mode] = an["total_wire_bytes"]
@@ -106,7 +105,7 @@ def test_dp_train_step_compression_multidev(tmp_path):
     script.write_text(MULTIDEV)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"  # fake-device CPU mesh, never the chip
     r = subprocess.run([sys.executable, str(script)], capture_output=True,
                        text=True, timeout=900, env=env,
                        cwd=os.path.join(_ROOT, "tests"))

@@ -108,11 +108,10 @@ def _kw(ep, bias, res, op2, nsc):
 def _oracle_f64(a, b, ep, **kw):
     """The f64 oracle: same spec, every operand upcast to float64, so the
     dot and the whole epilogue chain run at f64 (no hand-tuned ref)."""
-    from jax.experimental import enable_x64
     ep64 = Epilogue(**{**{f.name: getattr(ep, f.name)
                           for f in Epilogue.__dataclass_fields__.values()},
                        "out_dtype": jnp.float64})
-    with enable_x64():
+    with ref.x64():
         up = {k: (None if v is None
                   else jnp.asarray(np.asarray(v, np.float64)))
               for k, v in kw.items()}

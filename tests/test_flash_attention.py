@@ -57,8 +57,7 @@ def _budget(got, want64, want32):
 
 
 def _f64_prefill_ref(q, k, v, **kw):
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with ref.x64():
         return ref.flash_attention_ref(
             jnp.asarray(np.asarray(q, np.float64)),
             jnp.asarray(np.asarray(k, np.float64)),
@@ -131,8 +130,7 @@ def _decode_inputs(b, kv_len, n_kv, g, hd, seed):
 def _check_decode(b, kv_len, n_kv, g, hd, pos, seed, *, kind="global",
                   kv_tile=8):
     q, kc, vc = _decode_inputs(b, kv_len, n_kv, g, hd, seed)
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with ref.x64():
         want64 = ref.flash_decode_ref(
             jnp.asarray(np.asarray(q, np.float64)),
             jnp.asarray(np.asarray(kc, np.float64)),
@@ -181,8 +179,7 @@ def test_decode_einsum_fallback_same_budget():
     """The fixed einsum fallback stays within the same budget (it is the
     ring-buffer path's production implementation)."""
     q, kc, vc = _decode_inputs(2, 22, 2, 2, 16, 0)
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with ref.x64():
         want64 = ref.flash_decode_ref(
             jnp.asarray(np.asarray(q, np.float64)),
             jnp.asarray(np.asarray(kc, np.float64)),
@@ -211,8 +208,7 @@ def _paged_inputs(n_pool, ps, n_kv, g, hd, table, positions, seed):
 def _check_paged(n_pool, ps, n_kv, g, hd, table, positions, seed):
     q, kp, vp, tab, pos = _paged_inputs(n_pool, ps, n_kv, g, hd, table,
                                         positions, seed)
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with ref.x64():
         want64 = ref.paged_flash_decode_ref(
             jnp.asarray(np.asarray(q, np.float64)),
             jnp.asarray(np.asarray(kp, np.float64)),
@@ -277,8 +273,7 @@ def test_paged_prefill_chunk_s_gt_1():
     vp = _bf16(keys[2], 5, 8, 2, 16)
     tab = jnp.asarray([[0, 1]], jnp.int32)
     pos = jnp.asarray([[8, 9, 10, 11]], jnp.int32)
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with ref.x64():
         want64 = ref.paged_flash_decode_ref(
             jnp.asarray(np.asarray(q, np.float64)),
             jnp.asarray(np.asarray(kp, np.float64)),

@@ -14,7 +14,7 @@ def _run(*checks):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"  # fake-device CPU mesh, never the chip
     r = subprocess.run(
         [sys.executable, _SCRIPT, *checks],
         capture_output=True, text=True, timeout=600, env=env,
@@ -40,7 +40,7 @@ def test_schedule_equivalence_epilogue():
 
 
 def test_replicated_out():
-    _run("replicated_out")
+    _run("xyz_replicated_out")
 
 
 def test_overlapped_gather_hlo():

@@ -43,7 +43,7 @@ def _run_sweep(*args):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"  # fake-device CPU mesh, never the chip
     r = subprocess.run([sys.executable, _SWEEP, *args],
                        capture_output=True, text=True, timeout=1200,
                        env=env)

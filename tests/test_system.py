@@ -72,7 +72,6 @@ import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding
 from repro.configs import get_config
 from repro.launch.mesh import make_mesh
-from repro.core.sharding import use_mesh
 from repro.launch.hlo_analysis import analyze_hlo
 from repro.models.lm import Model
 from repro.optim import AdamWConfig, abstract_opt_state, opt_state_specs
@@ -91,7 +90,7 @@ for arch in ("internlm2-1.8b", "gemma2-27b"):
     ab = {"tokens": jax.ShapeDtypeStruct((4, 32), jnp.int32),
           "targets": jax.ShapeDtypeStruct((4, 32), jnp.int32)}
     ns = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jf = jax.jit(fn, in_shardings=(ns(model.param_specs()),
                      ns(opt_state_specs(model.param_specs(), opt_cfg)),
                      ns(batch_specs(cfg, mesh, "train"))))
@@ -113,7 +112,7 @@ def test_mini_dryrun_multidev(tmp_path):
     script.write_text(MINI_DRYRUN.replace(
         "REPO_SRC", repr(os.path.join(_ROOT, "src"))))
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"  # fake-device CPU mesh, never the chip
     r = subprocess.run([sys.executable, str(script)], capture_output=True,
                        text=True, timeout=900, env=env)
     assert r.returncode == 0, f"stdout:{r.stdout}\nstderr:{r.stderr}"
