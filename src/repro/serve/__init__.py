@@ -1,4 +1,4 @@
-from repro.serve.api import Request, RequestOutput, SamplingParams
+from repro.serve.api import Request, RequestOutput, SamplingParams, StepRecord
 from repro.serve.engine import ServeConfig, ServeEngine
 from repro.serve.kv_cache import PageAllocator, PagedKVCache
 from repro.serve.scheduler import PagedScheduler
@@ -9,6 +9,7 @@ __all__ = [
     "Request",
     "RequestOutput",
     "SamplingParams",
+    "StepRecord",
     "PageAllocator",
     "PagedKVCache",
     "PagedScheduler",

@@ -10,7 +10,9 @@ that surface:
     ``ServeConfig`` fields), validated as loudly as the engine config;
   * ``Request``        — one prompt plus its sampling params;
   * ``RequestOutput``  — the generated tokens plus the PR 5 structured
-    status/fault_step, per request instead of per batch lane.
+    status/fault_step, per request instead of per batch lane;
+  * ``StepRecord``     — what one scheduler step did, counted where the
+    work happened (``ServeEngine.last_step``).
 
 ``ServeEngine.submit()/step()/collect()`` consumes and produces these;
 ``generate()``/``generate_with_status()`` remain as thin fixed-batch
@@ -19,7 +21,7 @@ shims over the same scheduler.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Union
+from typing import Optional, Tuple, Union
 
 import numpy as np
 
@@ -103,3 +105,49 @@ class RequestOutput:
     @property
     def ok(self) -> bool:
         return self.status == STATUS_OK
+
+
+@dataclasses.dataclass(frozen=True)
+class StepRecord:
+    """What one ``PagedScheduler.step`` did, counted where the work
+    happened.  The scheduler keeps only its latest record
+    (``PagedScheduler.last_step``, re-exported as
+    ``ServeEngine.last_step``); a caller that wants a history copies it
+    after each step.  Request ids tie a request's steps together.
+
+    ``step``            index of the step on its scheduler, from 0.
+    ``queue_depth``     requests queued when the step started.
+    ``admitted``        ids admitted into lanes this step.
+    ``shed``            ids shed at admission (they never held a lane).
+    ``prefill_lanes``   lanes in the prefill dispatch with a prompt chunk.
+    ``prefill_rows``    real prompt rows in that dispatch.
+    ``prefill_rows_dispatched``  rows it computed (lanes x chunk; 0
+                        without a prefill dispatch).
+    ``decode_lanes``    lanes the decode dispatch stepped for a request.
+    ``decode_lanes_dispatched``  lanes it computed (0 without one).
+    ``picked``          lanes the token pick read.
+    ``pages_held``      pages mapped to lanes at the step's end.
+    ``pages_written``   of those, pages holding a written position.
+    ``pages_free``      pages left in the pool's free list.
+    ``lanes``           ``(id, prompt tokens prefilled, tokens picked,
+                        prompt length)`` of every occupied lane at the
+                        step's end, in lane order.
+    ``retired``         ``(id, status)`` of every request that left a
+                        lane this step.
+    """
+
+    step: int
+    queue_depth: int
+    admitted: Tuple[Union[int, str], ...]
+    shed: Tuple[Union[int, str], ...]
+    prefill_lanes: int
+    prefill_rows: int
+    prefill_rows_dispatched: int
+    decode_lanes: int
+    decode_lanes_dispatched: int
+    picked: int
+    pages_held: int
+    pages_written: int
+    pages_free: int
+    lanes: Tuple[Tuple[Union[int, str], int, int, int], ...]
+    retired: Tuple[Tuple[Union[int, str], str], ...]

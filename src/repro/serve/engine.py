@@ -63,8 +63,9 @@ from repro.robust.guards import (
     GenerateResult,
     NumericalHealthError,
 )
-from repro.serve.api import Request, RequestOutput, SamplingParams
+from repro.serve.api import Request, RequestOutput, SamplingParams, StepRecord
 from repro.serve.scheduler import PagedScheduler
+from repro.serve.trace import SUBMIT, span
 
 _ON_NONFINITE = ("quarantine", "raise", "off")
 
@@ -464,7 +465,8 @@ class ServeEngine:
 
     def submit(self, request: Request) -> None:
         """Queue one request (admitted into a lane as capacity frees)."""
-        self.scheduler.submit(request)
+        with span(SUBMIT):
+            self.scheduler.submit(request)
 
     def step(self, fault_plan=None) -> List[RequestOutput]:
         """Advance the scheduler one iteration: admissions, at most one
@@ -474,6 +476,12 @@ class ServeEngine:
         outs = self.scheduler.step(fault_plan)
         self._finished.extend(outs)
         return outs
+
+    @property
+    def last_step(self) -> Optional[StepRecord]:
+        """The default scheduler's record of its latest ``step()`` (None
+        before the first); see ``StepRecord``."""
+        return self._sched.last_step if self._sched is not None else None
 
     def collect(self) -> List[RequestOutput]:
         """Drain every finished-but-uncollected RequestOutput."""

@@ -38,6 +38,10 @@ assembly run while the device works, and only the token pick's host
 transfer synchronizes.  ``FaultPlan`` hooks ride at the same boundaries
 as the fixed-batch loop (``maybe_stall_lanes`` / ``perturb_logits_lanes``
 — per-lane step vectors instead of one global step).
+
+Each step runs inside the host span ``serve.step``, its phases inside
+the child spans ``repro.serve.trace.STEP_PHASES`` names, and leaves a
+``StepRecord`` of its counts in ``last_step``.
 """
 from __future__ import annotations
 
@@ -58,8 +62,9 @@ from repro.robust.guards import (
     STATUS_TIMEOUT,
     NumericalHealthError,
 )
-from repro.serve.api import Request, RequestOutput, SamplingParams
+from repro.serve.api import Request, RequestOutput, SamplingParams, StepRecord
 from repro.serve.kv_cache import PagedKVCache
+from repro.serve.trace import STEP, span
 
 
 @dataclasses.dataclass
@@ -117,6 +122,9 @@ class PagedScheduler:
         self._pick_gen = -1
         self._pick_const = None
         self._degr_dev = None
+        self._n_steps = 0
+        # the latest step's record (None before the first step)
+        self.last_step: Optional[StepRecord] = None
 
     # -- surface ---------------------------------------------------------------
 
@@ -159,20 +167,30 @@ class PagedScheduler:
     # -- one iteration ---------------------------------------------------------
 
     def step(self, fault_plan=None) -> List[RequestOutput]:
-        """Advance every phase one tick; returns requests finished NOW."""
+        """Advance every phase one tick; returns requests finished NOW.
+
+        The step runs inside the host span ``serve.step`` and its phases
+        inside its children (``repro.serve.trace``); ``last_step`` then
+        holds the step's ``StepRecord``."""
+        with span(STEP):
+            return self._step(fault_plan)
+
+    def _step(self, fault_plan) -> List[RequestOutput]:
         eng = self.engine
-        scfg = eng.scfg
         plan = fault_plan if (fault_plan is not None
                               and fault_plan.enabled) else None
         finished: List[RequestOutput] = []
         L = self.n_lanes
         fresh = np.zeros((L,), bool)
+        count = dict(step=self._n_steps, queue_depth=len(self.queue))
+        self._n_steps += 1
 
         # 1. admissions first, so a request admitted into a lane freed
         # LAST iteration rides this iteration's chunk dispatch instead of
         # waiting one more tick (page-allocator bookkeeping is a few
         # microseconds of host work)
-        self._admit(finished)
+        with span("serve.admit"):
+            count["admitted"], count["shed"] = self._admit(finished)
 
         # 2. chunked prefill: ONE chunk per prefilling lane, ALL such
         # lanes batched into a single [L, C] dispatch (idle lanes ride
@@ -181,85 +199,101 @@ class PagedScheduler:
         # over iterations instead of stalling in-flight decodes, while
         # same-time admissions stay in lockstep (what makes the
         # generate(batch) shim bitwise-match the fixed loop).
-        pre = [l for l, a in enumerate(self.lanes)
-               if a is not None and not a.prefilled]
-        completed = np.zeros((L,), bool)
+        with span("serve.chunk_build"):
+            pre = [l for l, a in enumerate(self.lanes)
+                   if a is not None and not a.prefilled]
+            dec = [l for l, a in enumerate(self.lanes)
+                   if a is not None and a.prefilled and a.tokens]
+            completed = np.zeros((L,), bool)
+            rows = 0
+            if pre:
+                tc = np.zeros((L, self.chunk), np.int32)
+                pc = np.full((L, self.chunk), -1, np.int32)
+                last = np.full((L,), -1, np.int32)
+                for l in pre:
+                    a = self.lanes[l]
+                    start = a.n_prefilled
+                    n = min(self.chunk, len(a.req.tokens) - start)
+                    tc[l, :n] = a.req.tokens[start:start + n]
+                    pc[l, :n] = np.arange(start, start + n, dtype=np.int32)
+                    last[l] = n - 1
+                    a.n_prefilled += n
+                    rows += n
+                    if a.prefilled:
+                        completed[l] = True  # row seeds the first pick
+                chunk_args = (jnp.asarray(tc), jnp.asarray(pc),
+                              self.kv.table_device(), jnp.asarray(last))
+            count.update(prefill_lanes=len(pre), prefill_rows=rows,
+                         prefill_rows_dispatched=L * self.chunk if pre else 0,
+                         decode_lanes=len(dec),
+                         decode_lanes_dispatched=L if dec else 0)
         chunk_rows = None
         if pre:
-            tc = np.zeros((L, self.chunk), np.int32)
-            pc = np.full((L, self.chunk), -1, np.int32)
-            last = np.full((L,), -1, np.int32)
-            for l in pre:
-                a = self.lanes[l]
-                start = a.n_prefilled
-                n = min(self.chunk, len(a.req.tokens) - start)
-                tc[l, :n] = a.req.tokens[start:start + n]
-                pc[l, :n] = np.arange(start, start + n, dtype=np.int32)
-                last[l] = n - 1
-                a.n_prefilled += n
-                if a.prefilled:
-                    completed[l] = True   # row seeds the first pick below
-            chunk_rows, self.kv.pools = eng._prefill_chunk(
-                eng.params, self.kv.pools, jnp.asarray(tc),
-                jnp.asarray(pc), self.kv.table_device(),
-                jnp.asarray(last))
+            with span("serve.prefill_dispatch"):
+                chunk_rows, self.kv.pools = eng._prefill_chunk(
+                    eng.params, self.kv.pools, *chunk_args)
 
-        # 3. decode: one [L]-wide step for every lane holding tokens
-        dec = [l for l, a in enumerate(self.lanes)
-               if a is not None and a.prefilled and a.tokens]
+        # 3. decode: one [L]-wide step for every lane holding tokens (a
+        # lane that finished its prompt above holds none yet); its inputs
+        # upload while the prefill chunk runs
         fp_logits = None
         if dec:
-            pos_np = np.full((L,), -1, np.int32)
-            for l in dec:
-                a = self.lanes[l]
-                pos_np[l] = len(a.req.tokens) + len(a.tokens) - 1
-            tok_dev = jnp.asarray(self._last_tok[:, None])
-            pos_dev = jnp.asarray(pos_np)
-            pt_dev = self.kv.table_device()
-            if (eng._decode_paged_fp is not None
-                    and any(self.lanes[l].degraded for l in dec)):
-                # dispatched BEFORE the donating step: it reads the pool
-                # buffers that step consumes
-                fp_logits, _ = eng._decode_paged_fp(
-                    eng._fp_params, self.kv.pools, tok_dev, pos_dev,
-                    pt_dev)
-            self._logits, self.kv.pools = eng._decode_paged(
-                eng.params, self.kv.pools, tok_dev, pos_dev, pt_dev)
+            with span("serve.decode_dispatch"):
+                pos_np = np.full((L,), -1, np.int32)
+                for l in dec:
+                    a = self.lanes[l]
+                    pos_np[l] = len(a.req.tokens) + len(a.tokens) - 1
+                tok_dev = jnp.asarray(self._last_tok[:, None])
+                pos_dev = jnp.asarray(pos_np)
+                pt_dev = self.kv.table_device()
+                if (eng._decode_paged_fp is not None
+                        and any(self.lanes[l].degraded for l in dec)):
+                    # dispatched BEFORE the donating step: it reads the
+                    # pool buffers that step consumes
+                    fp_logits, _ = eng._decode_paged_fp(
+                        eng._fp_params, self.kv.pools, tok_dev, pos_dev,
+                        pt_dev)
+                self._logits, self.kv.pools = eng._decode_paged(
+                    eng.params, self.kv.pools, tok_dev, pos_dev, pt_dev)
             fresh[dec] = True
 
         # 4. inject completed lanes' final-chunk logits rows into the
         # pick buffer — one masked dispatch for every lane that finished
         # its prompt this iteration
         if completed.any():
-            if self._logits is None:
-                self._logits = chunk_rows
-            else:
-                self._logits = eng._inject_rows(
-                    self._logits, chunk_rows, jnp.asarray(completed))
+            with span("serve.inject"):
+                if self._logits is None:
+                    self._logits = chunk_rows
+                else:
+                    self._logits = eng._inject_rows(
+                        self._logits, chunk_rows, jnp.asarray(completed))
             fresh |= completed
 
         # 5. faults + per-request deadlines (stall first, like the fixed
         # loop: a stalled host is exactly what the budget must convert)
-        steps = np.full((L,), -1, np.int64)
-        for l, a in enumerate(self.lanes):
-            if a is not None and fresh[l]:
-                steps[l] = len(a.tokens)
-        if plan is not None:
-            plan.maybe_stall_lanes(steps, self._stall_fired)
-        now = time.monotonic()
-        for l, a in enumerate(self.lanes):
-            if a is not None and a.deadline is not None \
-                    and now > a.deadline:
-                a.status = STATUS_TIMEOUT
-                a.fault_step = len(a.tokens)
-                self.timed_out = True
-                fresh[l] = False
-                steps[l] = -1
-                self._retire(l, finished)
-        if not fresh.any():
-            return finished
-        if plan is not None:
-            self._logits = plan.perturb_logits_lanes(steps, self._logits)
+        with span("serve.deadlines"):
+            steps = np.full((L,), -1, np.int64)
+            for l, a in enumerate(self.lanes):
+                if a is not None and fresh[l]:
+                    steps[l] = len(a.tokens)
+            if plan is not None:
+                plan.maybe_stall_lanes(steps, self._stall_fired)
+            now = time.monotonic()
+            for l, a in enumerate(self.lanes):
+                if a is not None and a.deadline is not None \
+                        and now > a.deadline:
+                    a.status = STATUS_TIMEOUT
+                    a.fault_step = len(a.tokens)
+                    self.timed_out = True
+                    fresh[l] = False
+                    steps[l] = -1
+                    self._retire(l, finished)
+            if not fresh.any():
+                self.last_step = self._record(count, 0, finished)
+                return finished
+            if plan is not None:
+                self._logits = plan.perturb_logits_lanes(steps,
+                                                         self._logits)
 
         # 6. one fused pick + health probe over all lanes.  The
         # lane-constant args (keys, sampling modes, calibration) come
@@ -267,40 +301,53 @@ class PagedScheduler:
         # uploads every iteration.  Non-fresh lanes carry step -1 — their
         # fold_in keys differ from a live lane's but their picks are
         # never read.
-        if self._pick_gen != self._lane_gen:
-            kb = np.zeros((L, 2), np.uint32)
-            greedy = np.ones((L,), bool)
-            temp = np.ones((L,), np.float32)
-            calib = np.ones((L,), np.float32)
-            degr = np.zeros((L,), bool)
-            for l, a in enumerate(self.lanes):
-                if a is None:
-                    continue
-                kb[l] = a.key_base
-                greedy[l] = a.sp.greedy
-                temp[l] = a.sp.temperature
-                calib[l] = a.calib
-                degr[l] = a.degraded
-            self._pick_const = (jnp.asarray(kb), jnp.asarray(greedy),
-                                jnp.asarray(temp), jnp.asarray(calib))
-            self._degr_dev = jnp.asarray(degr)
-            self._pick_gen = self._lane_gen
-        kb_d, greedy_d, temp_d, calib_d = self._pick_const
-        steps_d = jnp.asarray(steps.astype(np.int32))
-        pick_args = (kb_d, steps_d, greedy_d, temp_d, calib_d)
-        tok_j, fin_j, absmax_j, sat_j = eng._pick_paged(
-            self._logits, *pick_args)
-        if fp_logits is not None:
-            # degraded lanes pick from the fp32 fallback logits; the same
-            # keys keep healthy lanes bitwise unchanged
-            tok_fp, _, _, _ = eng._pick_paged(fp_logits, *pick_args)
-            tok_j = jnp.where(self._degr_dev, tok_fp, tok_j)
-        tok_np = np.asarray(tok_j)
-        fin_np = np.asarray(fin_j)
-        absmax_np = np.asarray(absmax_j)
-        sat_np = np.asarray(sat_j)
+        with span("serve.pick_dispatch"):
+            if self._pick_gen != self._lane_gen:
+                kb = np.zeros((L, 2), np.uint32)
+                greedy = np.ones((L,), bool)
+                temp = np.ones((L,), np.float32)
+                calib = np.ones((L,), np.float32)
+                degr = np.zeros((L,), bool)
+                for l, a in enumerate(self.lanes):
+                    if a is None:
+                        continue
+                    kb[l] = a.key_base
+                    greedy[l] = a.sp.greedy
+                    temp[l] = a.sp.temperature
+                    calib[l] = a.calib
+                    degr[l] = a.degraded
+                self._pick_const = (jnp.asarray(kb), jnp.asarray(greedy),
+                                    jnp.asarray(temp), jnp.asarray(calib))
+                self._degr_dev = jnp.asarray(degr)
+                self._pick_gen = self._lane_gen
+            kb_d, greedy_d, temp_d, calib_d = self._pick_const
+            steps_d = jnp.asarray(steps.astype(np.int32))
+            pick_args = (kb_d, steps_d, greedy_d, temp_d, calib_d)
+            tok_j, fin_j, absmax_j, sat_j = eng._pick_paged(
+                self._logits, *pick_args)
+            if fp_logits is not None:
+                # degraded lanes pick from the fp32 fallback logits; the
+                # same keys keep healthy lanes bitwise unchanged
+                tok_fp, _, _, _ = eng._pick_paged(fp_logits, *pick_args)
+                tok_j = jnp.where(self._degr_dev, tok_fp, tok_j)
+        with span("serve.pick_sync"):
+            tok_np = np.asarray(tok_j)
+            fin_np = np.asarray(fin_j)
+            absmax_np = np.asarray(absmax_j)
+            sat_np = np.asarray(sat_j)
 
         # 7. guards + commit + retire
+        with span("serve.commit"):
+            self._commit(fresh, tok_np, fin_np, absmax_np, sat_np,
+                         finished)
+            self.last_step = self._record(count, int(fresh.sum()),
+                                          finished)
+        return finished
+
+    def _commit(self, fresh, tok_np, fin_np, absmax_np, sat_np,
+                finished: List[RequestOutput]) -> None:
+        scfg = self.engine.scfg
+        L = self.n_lanes
         guards_on = scfg.guards and scfg.on_nonfinite != "off"
         sat_on = scfg.guards and scfg.int8
         if guards_on and scfg.on_nonfinite == "raise":
@@ -339,15 +386,39 @@ class PagedScheduler:
             if (a.sp.eos_id is not None and tk == a.sp.eos_id) \
                     or len(a.tokens) >= a.sp.max_new_tokens:
                 self._retire(l, finished)
-        return finished
+
+    def _record(self, count, picked: int,
+                finished: List[RequestOutput]) -> StepRecord:
+        """The step's record, from the counts taken as it ran and the
+        lanes and pages at its end."""
+        ps = self.kv.page_size
+        lanes = tuple((a.req.id, a.n_prefilled, len(a.tokens),
+                       len(a.req.tokens))
+                      for a in self.lanes if a is not None)
+        # a lane has written its prompt chunks and, per decode, the token
+        # before its newest (the newest is written by the next decode)
+        written = sum(-(-(pre + max(tok - 1, 0)) // ps)
+                      for _, pre, tok, _ in lanes)
+        return StepRecord(
+            **count, picked=picked,
+            pages_held=sum(len(p) for p in self.kv.lane_pages
+                           if p is not None),
+            pages_written=written,
+            pages_free=self.kv.allocator.n_free,
+            lanes=lanes,
+            retired=tuple((o.id, o.status) for o in finished
+                          if o.status != STATUS_SHED))
 
     # -- internals -------------------------------------------------------------
 
-    def _admit(self, finished: List[RequestOutput]) -> None:
+    def _admit(self, finished: List[RequestOutput]):
+        """Admit queued requests into free lanes; returns the ids
+        admitted and the ids shed."""
+        admitted, shed = [], []
         while self.queue:
             free = [l for l, a in enumerate(self.lanes) if a is None]
             if not free:
-                return
+                break
             req, sp = self.queue[0]
             total = len(req.tokens) + sp.max_new_tokens
             if not self.kv.fits_ever(total):
@@ -362,10 +433,11 @@ class PagedScheduler:
                     id=req.id, tokens=np.zeros((0,), np.int32),
                     status=STATUS_SHED, fault_step=-1, n_steps=0,
                     prompt_len=0))
+                shed.append(req.id)
                 continue
             l = free[0]
             if not self.kv.admit(l, total):
-                return  # transient page exhaustion: stay queued
+                break  # transient page exhaustion: stay queued
             self.queue.popleft()
             a = _Lane(req=req, sp=sp, seq=self._seq,
                       key_base=self.engine._request_key(req.seed))
@@ -375,6 +447,8 @@ class PagedScheduler:
                 a.deadline = time.monotonic() + scfg.request_timeout_s
             self.lanes[l] = a
             self._lane_gen += 1
+            admitted.append(req.id)
+        return tuple(admitted), tuple(shed)
 
     def _retire(self, lane: int, finished: List[RequestOutput]) -> None:
         a = self.lanes[lane]
