@@ -471,40 +471,174 @@ def flash_decode_pallas(q, k_cache, v_cache, pos, *, kind="global",
 # decode: paged flash-decode Pallas kernel (gather-in-kernel)
 # ---------------------------------------------------------------------------
 
-def _paged_decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref,
-                         m_ref, l_ref, acc_ref, *, ps: int, kind: str,
+# Positions one program of the paged kernel covers, at most: enough to
+# amortize the per-program cost over many pages, few enough that a
+# lane's last, partly live block wastes little.
+_PAGED_BLOCK_POSITIONS = 512
+
+
+def _paged_pages_per_block(ps: int, n_kv: int, hd_p: int, g_p: int,
+                           itemsize: int, p_max: int) -> int:
+    """Pages per program: the largest power of two with at most
+    ``_PAGED_BLOCK_POSITIONS`` positions whose VMEM (K and V pages in
+    two slots, the partials' output blocks double-buffered) fits the
+    scoped-VMEM budget, and no more than the table needs."""
+    page_in = ps * n_kv * hd_p * itemsize
+    # acc, and m and l with (n_kv, g_p) padded to (8, 128) f32 tiles
+    page_out = (n_kv * g_p * hd_p + 2 * _ceil_mult(n_kv, 8) * 128) * 4
+    ppb = 1
+    while (2 * ppb * ps <= _PAGED_BLOCK_POSITIONS and ppb < p_max
+           and 2 * ppb * (4 * page_in + 2 * page_out)
+           <= TPU_V5E.vmem_budget):
+        ppb *= 2
+    return ppb
+
+
+def _paged_live_pages(positions, n_pages: int, ps: int, *, kind: str,
+                      window: int):
+    """First and last logical page each lane's position attends, [B]
+    each; an idle lane (position -1) gets the empty range (0, -1).
+    'global' attends pages 0..pos//ps; 'local' drops pages wholly
+    before ``pos - window + 1``; 'chunked' those before the position's
+    chunk."""
+    pos = positions
+    if kind == "local":
+        first = jnp.maximum(pos - window + 1, 0)
+    elif kind == "chunked":
+        first = (pos // window) * window
+    else:
+        first = jnp.zeros_like(pos)
+    lo = first // ps
+    hi = jnp.minimum(pos // ps, n_pages - 1)
+    idle = pos < 0
+    return jnp.where(idle, 0, lo), jnp.where(idle, -1, hi)
+
+
+def _paged_decode_kernel(table_ref, pos_ref, lo_ref, hi_ref, nxt_ref,
+                         q_ref, k_hbm, v_hbm, m_ref, l_ref, acc_ref,
+                         k_buf, v_buf, sems, slot_ref, *, ps: int,
+                         ppb: int, p_pad: int, n_lanes: int, kind: str,
                          window: int, softcap, scale: float, g_p: int,
                          n_kv: int):
-    """Grid = (B, KV, P): one program per (lane, kv head, logical page).
-    The page gather happens in the BlockSpec index map (scalar-prefetched
-    page table -> pool row), so only mapped pages move — unmapped slots
-    read the trash page and are masked to exact zeros here."""
-    lane, page = pl.program_id(0), pl.program_id(2)
+    """Grid = (B, P/ppb), walked in order: one program per (lane, block
+    of ``ppb`` logical pages), all KV heads.  A block holding no page
+    the lane's position attends (past the position, before its window,
+    or an idle lane) moves no bytes and runs no dots: it writes the
+    fully masked partial.  A live block's pages arrive by async copy
+    from the pools left in HBM, started one live block ahead into the
+    other of two VMEM slots, so the copies overlap the previous block's
+    dots.  Each page yields the same per-page partial the one-page
+    program computed: the contract of ``combine_tile_partials``."""
+    lane, blk = pl.program_id(0), pl.program_id(1)
     pos = pos_ref[lane]
-    q = q_ref[0, 0]                                 # (g_p, hd_p)
-    k_t = k_ref[0]                                  # (ps, hd_p)
-    v_t = v_ref[0]
-    s = jax.lax.dot_general(q, k_t, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s * scale
-    if softcap:
-        s = softcap * jnp.tanh(s / softcap)
-    kvpos = (page * ps
-             + jax.lax.broadcasted_iota(jnp.int32, (g_p, ps), 1))
-    valid = (table_ref[lane, page] >= 0) & (kvpos <= pos) & (pos >= 0)
-    if kind == "local":
-        valid &= (pos - kvpos) < window
-    elif kind == "chunked":
-        valid &= (kvpos // window) == (pos // window)
-    s = jnp.where(valid, s, _NEG)
-    m_t = jnp.max(s, axis=-1)
-    p = jnp.where(valid, jnp.exp(s - m_t[:, None]), 0.0)
-    l_t = jnp.sum(p, axis=-1)
-    acc_t = jax.lax.dot_general(p, v_t, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    m_ref[0, 0, 0] = m_t[None]
-    l_ref[0, 0, 0] = l_t[None]
-    acc_ref[0, 0, 0] = acc_t
+
+    def copy_pages(ln, b, slot, start: bool):
+        """Start (or wait for) the copies of the pages of block ``b`` of
+        lane ``ln``: a page is copied iff it is mapped and the position
+        attends it.  A loop, not unrolled Python, so that tracing the
+        kernel costs the same for any block size."""
+        def page(j, carry):
+            p = b * ppb + j
+            phys = table_ref[ln * p_pad + p]
+
+            @pl.when((lo_ref[ln] <= p) & (p <= hi_ref[ln]) & (phys >= 0))
+            def _():
+                for hbm, buf, sem in ((k_hbm, k_buf, sems.at[0, slot]),
+                                      (v_hbm, v_buf, sems.at[1, slot])):
+                    c = pltpu.make_async_copy(hbm.at[phys], buf.at[slot, j],
+                                              sem)
+                    if start:
+                        c.start()
+                    else:
+                        c.wait()
+            return carry
+
+        jax.lax.fori_loop(0, ppb, page, 0)
+
+    def block_range(ln):
+        return lo_ref[ln] // ppb, hi_ref[ln] // ppb
+
+    @pl.when((lane == 0) & (blk == 0))
+    def _first():
+        # a slot a page was not copied into holds zeros or an earlier
+        # page, so a masked page's 0 * v is 0, never an uninitialized NaN
+        v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        f = nxt_ref[0]
+
+        @pl.when(f < n_lanes)
+        def _():
+            copy_pages(f, block_range(f)[0], 0, start=True)
+
+    lo_b, hi_b = block_range(lane)
+    # the same test the wrapper's ``nxt`` applies, so the copies started
+    # one live block ahead are the ones the next live block waits for
+    live = (lo_ref[lane] <= hi_ref[lane]) & (lo_b <= blk) & (blk <= hi_b)
+
+    @pl.when(live)
+    def _live():
+        slot = slot_ref[0]
+        more = blk < hi_b
+        nl = jnp.where(more, lane, nxt_ref[lane + 1])
+
+        @pl.when(nl < n_lanes)
+        def _():
+            copy_pages(nl, jnp.where(more, blk + 1, block_range(nl)[0]),
+                       1 - slot, start=True)
+
+        copy_pages(lane, blk, slot, start=False)
+
+        kvpos = (blk * ppb * ps
+                 + jax.lax.broadcasted_iota(jnp.int32, (ppb, g_p, ps), 0)
+                 * ps
+                 + jax.lax.broadcasted_iota(jnp.int32, (ppb, g_p, ps), 2))
+        valid = kvpos <= pos
+        if kind == "local":
+            valid &= (pos - kvpos) < window
+        elif kind == "chunked":
+            valid &= (kvpos // window) == (pos // window)
+        for h in range(n_kv):
+            q = jnp.broadcast_to(q_ref[0, h][None], (ppb,) + q_ref.shape[2:])
+            k_t = k_buf[slot, :, :, h, :]            # (ppb, ps, hd_p)
+            v_t = v_buf[slot, :, :, h, :]
+            s = jax.lax.dot_general(q, k_t, (((2,), (2,)), ((0,), (0,))),
+                                    preferred_element_type=jnp.float32)
+            s = s * scale
+            if softcap:
+                s = softcap * jnp.tanh(s / softcap)
+            s = jnp.where(valid, s, _NEG)
+            m_t = jnp.max(s, axis=-1)                # (ppb, g_p)
+            p = jnp.where(valid, jnp.exp(s - m_t[..., None]), 0.0)
+            l_t = jnp.sum(p, axis=-1)
+            acc_t = jax.lax.dot_general(
+                p, v_t, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)  # (ppb, g_p, hd_p)
+            m_ref[:, 0, h, :] = m_t
+            l_ref[:, 0, h, :] = l_t
+            acc_ref[:, 0, h] = acc_t
+
+        # a page the position attends but the table leaves unmapped
+        # is masked, as in the XLA mirror and the oracle
+        def mask_unmapped(j, carry):
+            page = blk * ppb + j
+
+            @pl.when((table_ref[lane * p_pad + page] < 0)
+                     & (page <= hi_ref[lane]))
+            def _():
+                m_ref[j] = jnp.full_like(m_ref[j], _NEG)
+                l_ref[j] = jnp.zeros_like(l_ref[j])
+                acc_ref[j] = jnp.zeros_like(acc_ref[j])
+            return carry
+
+        jax.lax.fori_loop(0, ppb, mask_unmapped, 0)
+
+        slot_ref[0] = 1 - slot
+
+    @pl.when(jnp.logical_not(live))
+    def _dead():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
 
 @functools.partial(
@@ -514,68 +648,81 @@ def paged_flash_decode_pallas(q, k_pool, v_pool, page_table, positions, *,
                               kind="global", window=0, softcap=None,
                               interpret=False) -> jnp.ndarray:
     """Paged flash decode, gather-in-kernel: q [B, 1, KV, G, hd] against
-    pools [NP, PS, KV, hd] through ``page_table`` [B, P] (-1 = unmapped
-    -> trash page NP-1, masked to exact zeros) at per-lane ``positions``
-    [B] (-1 = idle lane -> all-zero output).  The KV tile is one page;
-    partials combine outside the kernel with the same deterministic fold
+    pools [NP, PS, KV, hd] through ``page_table`` [B, P] (-1 = unmapped,
+    masked to exact zeros) at per-lane ``positions`` [B] (-1 = idle lane
+    -> all-zero output).  The KV tile is one page; a program covers a
+    block of pages and only the pages the position attends are read.
+    Partials combine outside the kernel with the same deterministic fold
     as the dense path.
     """
     b, s_q, n_kv, g, hd = q.shape
     assert s_q == 1, "paged flash kernel is decode-only (S == 1)"
-    n_pool, ps = k_pool.shape[0], k_pool.shape[1]
+    ps = k_pool.shape[1]
     p_max = page_table.shape[1]
     hd_p = max(_ceil_mult(hd, 128), 128)
     g_p = _ceil_mult(g, 8)
+    ppb = _paged_pages_per_block(ps, n_kv, hd_p, g_p,
+                                 jnp.dtype(k_pool.dtype).itemsize, p_max)
+    n_blk = _ceil_div(p_max, ppb)
+    p_pad = n_blk * ppb
 
     qr = _pad_axis(_pad_axis(q.reshape(b, n_kv, g, hd), 2, g_p), 3, hd_p)
-    kr = jnp.moveaxis(k_pool, 2, 1).reshape(n_pool * n_kv, ps, hd)
-    vr = jnp.moveaxis(v_pool, 2, 1).reshape(n_pool * n_kv, ps, hd)
-    kr = _pad_axis(kr, 2, hd_p)
-    vr = _pad_axis(vr, 2, hd_p)
-    table = jnp.asarray(page_table, jnp.int32)
+    # the pools are read in their stored layout; only a head size off
+    # the 128-lane tiling pays a padded copy
+    kp = _pad_axis(k_pool, 3, hd_p)
+    vp = _pad_axis(v_pool, 3, hd_p)
+    table = _pad_axis(jnp.asarray(page_table, jnp.int32), 1, p_pad)
+    table = jnp.where(jnp.arange(p_pad) < p_max, table, -1).reshape(-1)
     pos = jnp.asarray(positions, jnp.int32).reshape(b)
-
-    def pool_row(lane, h, page, table_ref, pos_ref):
-        t = table_ref[lane, page]
-        return (jnp.where(t >= 0, t, n_pool - 1) * n_kv + h, 0, 0)
+    lo, hi = _paged_live_pages(pos, p_max, ps, kind=kind, window=window)
+    # nxt[i]: the first lane >= i with a page to read (b if none)
+    lanes = jnp.where(hi >= lo, jnp.arange(b, dtype=jnp.int32), b)
+    nxt = jnp.concatenate([jax.lax.cummin(lanes, reverse=True),
+                           jnp.full((1,), b, jnp.int32)])
 
     kernel = functools.partial(
-        _paged_decode_kernel, ps=ps, kind=kind, window=window,
-        softcap=softcap, scale=float(hd) ** -0.5, g_p=g_p, n_kv=n_kv)
+        _paged_decode_kernel, ps=ps, ppb=ppb, p_pad=p_pad, n_lanes=b,
+        kind=kind, window=window, softcap=softcap,
+        scale=float(hd) ** -0.5, g_p=g_p, n_kv=n_kv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, n_kv, p_max),
+        num_scalar_prefetch=5,
+        grid=(b, n_blk),
         in_specs=[
-            pl.BlockSpec((1, 1, g_p, hd_p),
-                         lambda lane, h, page, t, p: (lane, h, 0, 0)),
-            pl.BlockSpec((1, ps, hd_p), pool_row),
-            pl.BlockSpec((1, ps, hd_p), pool_row),
+            pl.BlockSpec((1, n_kv, g_p, hd_p),
+                         lambda lane, blk, *_: (lane, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pltpu.ANY),
         ],
-        # m/l carry a unit axis so each block's trailing dims are whole
-        # array dims (the TPU block-shape rule)
         out_specs=[
-            pl.BlockSpec((1, 1, 1, 1, g_p),
-                         lambda lane, h, page, t, p: (page, lane, h, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1, g_p),
-                         lambda lane, h, page, t, p: (page, lane, h, 0, 0)),
-            pl.BlockSpec((1, 1, 1, g_p, hd_p),
-                         lambda lane, h, page, t, p: (page, lane, h, 0, 0)),
+            pl.BlockSpec((ppb, 1, n_kv, g_p),
+                         lambda lane, blk, *_: (blk, lane, 0, 0)),
+            pl.BlockSpec((ppb, 1, n_kv, g_p),
+                         lambda lane, blk, *_: (blk, lane, 0, 0)),
+            pl.BlockSpec((ppb, 1, n_kv, g_p, hd_p),
+                         lambda lane, blk, *_: (blk, lane, 0, 0, 0)),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb, ps, n_kv, hd_p), kp.dtype),
+            pltpu.VMEM((2, ppb, ps, n_kv, hd_p), vp.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     m_t, l_t, acc_t = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((p_max, b, n_kv, 1, g_p), jnp.float32),
-            jax.ShapeDtypeStruct((p_max, b, n_kv, 1, g_p), jnp.float32),
-            jax.ShapeDtypeStruct((p_max, b, n_kv, g_p, hd_p), jnp.float32),
+            jax.ShapeDtypeStruct((p_pad, b, n_kv, g_p), jnp.float32),
+            jax.ShapeDtypeStruct((p_pad, b, n_kv, g_p), jnp.float32),
+            jax.ShapeDtypeStruct((p_pad, b, n_kv, g_p, hd_p), jnp.float32),
         ],
+        # the slots carry copies from one program into the next, so
+        # the grid runs in order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=TPU_V5E.vmem_bytes),
         interpret=interpret,
-    )(table, pos, qr, kr, vr)
-    out = combine_tile_partials(m_t[..., 0, :], l_t[..., 0, :],
-                                acc_t)               # [B, KV, g_p, hd_p]
+    )(table, pos, lo, hi, nxt, qr, kp, vp)
+    out = combine_tile_partials(m_t, l_t, acc_t)     # [B, KV, g_p, hd_p]
     out = out[:, :, :g, :hd]
     return out[:, None].astype(q.dtype)

@@ -125,6 +125,12 @@ class StepRecord:
                         without a prefill dispatch).
     ``decode_lanes``    lanes the decode dispatch stepped for a request.
     ``decode_lanes_dispatched``  lanes it computed (0 without one).
+    ``decode_pages_live``  pages the decode's attention reads: over the
+                        lanes it stepped for a request, the pages
+                        holding a position at or below the lane's
+                        position (0 without a decode).  Beside
+                        ``decode_lanes_dispatched`` x pages a lane, the
+                        share of the page table the kernel skips.
     ``picked``          lanes the token pick read.
     ``pages_held``      pages mapped to lanes at the step's end.
     ``pages_written``   of those, pages holding a written position.
@@ -145,6 +151,7 @@ class StepRecord:
     prefill_rows_dispatched: int
     decode_lanes: int
     decode_lanes_dispatched: int
+    decode_pages_live: int
     picked: int
     pages_held: int
     pages_written: int

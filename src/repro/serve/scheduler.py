@@ -226,7 +226,8 @@ class PagedScheduler:
             count.update(prefill_lanes=len(pre), prefill_rows=rows,
                          prefill_rows_dispatched=L * self.chunk if pre else 0,
                          decode_lanes=len(dec),
-                         decode_lanes_dispatched=L if dec else 0)
+                         decode_lanes_dispatched=L if dec else 0,
+                         decode_pages_live=0)
         chunk_rows = None
         if pre:
             with span("serve.prefill_dispatch"):
@@ -243,6 +244,8 @@ class PagedScheduler:
                 for l in dec:
                     a = self.lanes[l]
                     pos_np[l] = len(a.req.tokens) + len(a.tokens) - 1
+                count["decode_pages_live"] = int(
+                    (pos_np[dec] // self.kv.page_size + 1).sum())
                 tok_dev = jnp.asarray(self._last_tok[:, None])
                 pos_dev = jnp.asarray(pos_np)
                 pt_dev = self.kv.table_device()

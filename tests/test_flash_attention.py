@@ -205,21 +205,22 @@ def _paged_inputs(n_pool, ps, n_kv, g, hd, table, positions, seed):
             jnp.asarray(positions, jnp.int32))
 
 
-def _check_paged(n_pool, ps, n_kv, g, hd, table, positions, seed):
+def _check_paged(n_pool, ps, n_kv, g, hd, table, positions, seed, **kw):
     q, kp, vp, tab, pos = _paged_inputs(n_pool, ps, n_kv, g, hd, table,
                                         positions, seed)
     with ref.x64():
         want64 = ref.paged_flash_decode_ref(
             jnp.asarray(np.asarray(q, np.float64)),
             jnp.asarray(np.asarray(kp, np.float64)),
-            jnp.asarray(np.asarray(vp, np.float64)), tab, pos)
+            jnp.asarray(np.asarray(vp, np.float64)), tab, pos, **kw)
     want32 = ref.paged_flash_decode_ref(q.astype(jnp.float32),
                                         kp.astype(jnp.float32),
-                                        vp.astype(jnp.float32), tab, pos)
-    xla = fa.paged_flash_decode_xla(q, kp, vp, tab, pos, kv_tile=8)
+                                        vp.astype(jnp.float32), tab, pos,
+                                        **kw)
+    xla = fa.paged_flash_decode_xla(q, kp, vp, tab, pos, kv_tile=8, **kw)
     _budget(xla, want64, want32)
     pal = fa.paged_flash_decode_pallas(q, kp, vp, tab, pos.reshape(-1),
-                                       interpret=True)
+                                       interpret=True, **kw)
     _budget(pal, want64, want32)
     return xla, pal
 
@@ -229,6 +230,7 @@ PAGED_CASES = [
     (9, 8, [[0, 1, -1, -1], [3, 4, 5, -1]], [[13], [20]], 0),
     (5, 8, [[0, -1], [2, 3]], [[7], [15]], 1),
     (3, 4, [[1]], [[2]], 2),               # single page, KV < one tile
+    (9, 8, [[0, -1, 2, -1]], [[29]], 3),   # unmapped pages at or below pos
 ]
 
 
@@ -263,6 +265,71 @@ def test_paged_neighbor_isolation_bitwise():
     np.testing.assert_array_equal(
         np.asarray(a[0].astype(jnp.float32)),
         np.asarray(b[0].astype(jnp.float32)))
+
+
+# Several page blocks per lane: pages of 64 positions give 8-page
+# blocks, so a 20-page table is three blocks.  Lane 0 ends mid-block 1,
+# lane 1 in the last block, lane 2 is idle, lane 3 ends in block 0;
+# mapped pages run past each position, as the scheduler maps prompt
+# plus answer at admission.
+MB_PS, MB_PAGES, MB_POOL = 64, 20, 80
+MB_POS = [700, 1270, -1, 20]
+MB_FIRST = [0, 20, 45, 60]            # each lane's run of physical pages
+
+
+def _multi_block_table(pages, extra=0):
+    """Lane l maps physical pages from its own run, covering its
+    position's pages plus ``extra`` more (lane 2 maps 3 pages while
+    idle, as a prefilling lane does); the rest of the row is -1."""
+    table = np.full((len(MB_POS), pages), -1, np.int32)
+    for lane, (p, first) in enumerate(zip(MB_POS, MB_FIRST)):
+        n = min((p // MB_PS + 1 if p >= 0 else 3) + extra, pages)
+        table[lane, :n] = np.arange(first, first + n)
+    assert len(set(table[table >= 0].tolist())) == int((table >= 0).sum())
+    assert table.max() < MB_POOL - 1
+    return table.tolist()
+
+
+def test_paged_table_spans_several_blocks():
+    ppb = fa._paged_pages_per_block(MB_PS, 2, 128, 8, 2, MB_PAGES)
+    assert MB_PAGES > 2 * ppb and any(
+        p >= 0 and (p // MB_PS + 1) % ppb for p in MB_POS)
+    _check_paged(MB_POOL, MB_PS, 2, 2, 16, _multi_block_table(MB_PAGES),
+                 [[p] for p in MB_POS], 11)
+
+
+def test_paged_skip_changes_no_result():
+    """The kernel reads only the pages at or below each position; what
+    lies past it (-1 columns widening the table, or pages mapped ahead
+    of the position) leaves every lane's output bitwise unchanged."""
+    pos = jnp.asarray(MB_POS, jnp.int32)
+    q, kp, vp, _, _ = _paged_inputs(MB_POOL, MB_PS, 2, 2, 16,
+                                    _multi_block_table(MB_PAGES),
+                                    [[p] for p in MB_POS], 12)
+
+    def run(table):
+        out = fa.paged_flash_decode_pallas(
+            q, kp, vp, jnp.asarray(table, jnp.int32), pos, interpret=True)
+        return np.asarray(out.astype(jnp.float32))
+
+    base = run(_multi_block_table(MB_PAGES))
+    np.testing.assert_array_equal(
+        run(_multi_block_table(MB_PAGES + 11)), base)
+    np.testing.assert_array_equal(
+        run(_multi_block_table(MB_PAGES, extra=7)), base)
+    assert float(np.max(np.abs(base[2]))) == 0.0
+
+
+@pytest.mark.parametrize("kind,window,softcap", [
+    ("local", 300, None),      # block 0 lies wholly before lane 1's window
+    ("local", 100, 20.0),      # so do blocks 0 and 1
+    ("chunked", 512, None),    # block 0 lies outside lane 0's chunk
+    ("global", 0, 30.0),
+])
+def test_paged_kinds_match_oracle(kind, window, softcap):
+    _check_paged(MB_POOL, MB_PS, 2, 2, 16, _multi_block_table(MB_PAGES),
+                 [[p] for p in MB_POS], 13, kind=kind, window=window,
+                 softcap=softcap)
 
 
 def test_paged_prefill_chunk_s_gt_1():

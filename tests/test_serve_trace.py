@@ -54,28 +54,29 @@ def _lanes(sched):
 # 2 pages, B (5 + 2) one; C (20 + 4) needs 3 and waits for pages while A
 # holds 2 of the 3 left after B retires.  Fields: step, queue depth,
 # admitted, shed, prefill lanes / rows / rows dispatched, decode lanes /
-# lanes dispatched, picked, pages held / written / free, lanes, retired.
+# lanes dispatched / pages live, picked, pages held / written / free,
+# lanes, retired.
 EXPECTED = [
-    (0, 4, ("A", "B"), ("D",), 2, 13, 16, 0, 0, 1, 3, 2, 1,
+    (0, 4, ("A", "B"), ("D",), 2, 13, 16, 0, 0, 0, 1, 3, 2, 1,
      (("A", 8, 0, 12), ("B", 5, 1, 5)), ()),
-    (1, 1, (), (), 1, 4, 16, 1, 2, 2, 2, 2, 2,
+    (1, 1, (), (), 1, 4, 16, 1, 2, 1, 2, 2, 2, 2,
      (("A", 12, 1, 12),), (("B", "ok"),)),
-    (2, 1, (), (), 0, 0, 0, 1, 2, 1, 2, 2, 2,
+    (2, 1, (), (), 0, 0, 0, 1, 2, 2, 1, 2, 2, 2,
      (("A", 12, 2, 12),), ()),
-    (3, 1, (), (), 0, 0, 0, 1, 2, 1, 0, 0, 4,
+    (3, 1, (), (), 0, 0, 0, 1, 2, 2, 1, 0, 0, 4,
      (), (("A", "ok"),)),
     # C admitted; a chunk with no pick: the step returns after deadlines
-    (4, 1, ("C",), (), 1, 8, 16, 0, 0, 0, 3, 1, 1,
+    (4, 1, ("C",), (), 1, 8, 16, 0, 0, 0, 0, 3, 1, 1,
      (("C", 8, 0, 20),), ()),
-    (5, 0, (), (), 1, 8, 16, 0, 0, 0, 3, 2, 1,
+    (5, 0, (), (), 1, 8, 16, 0, 0, 0, 0, 3, 2, 1,
      (("C", 16, 0, 20),), ()),
-    (6, 0, (), (), 1, 4, 16, 0, 0, 1, 3, 3, 1,
+    (6, 0, (), (), 1, 4, 16, 0, 0, 0, 1, 3, 3, 1,
      (("C", 20, 1, 20),), ()),
-    (7, 0, (), (), 0, 0, 0, 1, 2, 1, 3, 3, 1,
+    (7, 0, (), (), 0, 0, 0, 1, 2, 3, 1, 3, 3, 1,
      (("C", 20, 2, 20),), ()),
-    (8, 0, (), (), 0, 0, 0, 1, 2, 1, 3, 3, 1,
+    (8, 0, (), (), 0, 0, 0, 1, 2, 3, 1, 3, 3, 1,
      (("C", 20, 3, 20),), ()),
-    (9, 0, (), (), 0, 0, 0, 1, 2, 1, 0, 0, 4,
+    (9, 0, (), (), 0, 0, 0, 1, 2, 3, 1, 0, 0, 4,
      (), (("C", "ok"),)),
 ]
 
@@ -106,6 +107,31 @@ def test_step_record_matches_hand_count(model, params):
     assert got == EXPECTED
     outs = {o.id: o for o in eng.collect()}
     assert outs["D"].status == "shed" and len(outs["C"].tokens) == 4
+
+
+def test_decode_pages_live_counts_pages_at_or_below_positions(model,
+                                                              params):
+    """The pages the decode reads, recomputed from the previous step's
+    lane snapshot: a lane that had its whole prompt and a token decodes
+    at prompt + tokens - 1 and reads the pages up to that position's.
+    A step with no decode counts 0."""
+    ps = 8
+    eng = _engine(model, params, n_lanes=2, page_size=ps, prefill_chunk=8,
+                  max_seq_len=32, n_pages=4)
+    _submit_fixed(eng, model)
+    prev, live = (), []
+    while eng.pending:
+        eng.step()
+        rec = eng.last_step
+        want = sum((plen + tok - 1) // ps + 1
+                   for _, pre, tok, plen in prev if pre == plen and tok)
+        assert rec.decode_pages_live == want, (rec.step, prev)
+        if rec.decode_lanes == 0:
+            assert rec.decode_pages_live == 0
+        live.append(rec.decode_pages_live)
+        prev = rec.lanes
+    assert live == [e[9] for e in EXPECTED]
+    assert any(live) and not all(live)
 
 
 def test_step_record_is_frozen(model, params):

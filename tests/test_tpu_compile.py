@@ -135,6 +135,19 @@ def test_paged_flash_decode_compiles(one_chip):
     assert hlo.count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("kw", ({}, {"kind": "local", "window": 4096,
+                                      "softcap": 50.0}),
+                         ids=("global", "local_softcap"))
+def test_paged_flash_decode_compiles_at_chat_geometry(one_chip, kw):
+    """The chat cell's geometry: 8 lanes of 320 pages (5120 positions)
+    over an 866-page pool, so the walk spans several page blocks."""
+    pool = ((866, PAGE, KV, HD), BF)
+    hlo = _compile(lambda *a: paged_flash_decode_pallas(*a, **kw), one_chip,
+                   ((LANES, 1, KV, G, HD), BF), pool, pool,
+                   ((LANES, 320), jnp.int32), ((LANES,), jnp.int32))
+    assert hlo.count("tpu_custom_call") == 1
+
+
 @pytest.mark.parametrize("int8,kernels", ((False, 6), (True, 9)))
 def test_paged_decode_step_compiles(one_chip, monkeypatch, int8, kernels):
     """The whole paged decode step the scheduler serves, at full widths:
