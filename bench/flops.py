@@ -1,5 +1,7 @@
-"""Operations and bytes of the model's work, from a configuration's
-shapes alone.
+"""Operations and bytes of a dense GQA model's work (every layer's
+attention global, its whole MLP active), from a configuration's shapes
+alone.  ``bench/arch/dense_gqa.py`` exports these counts; a module of
+another layout brings its own, built on the helpers here where they fit.
 
 Counts are of useful work: real tokens at their real context lengths,
 never the padding rows or idle lanes a step may carry, so they stay the

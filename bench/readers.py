@@ -10,7 +10,7 @@ from __future__ import annotations
 
 from typing import List, Optional
 
-import flops
+import manifest
 import xtrace as tr
 
 
@@ -33,16 +33,17 @@ def program_ms(program: str):
 
 def step_mfu(run) -> Optional[float]:
     """Model operations of the useful tokens of the window's iterations
-    over (their summed wall time x the bf16 peak), in %."""
+    (the counts of the cell's architecture module) over (their summed
+    wall time x the bf16 peak), in %."""
     peak = run.peaks.get("bf16_flops")
     its = run.window_iters()
     wall = sum(it.t1 - it.t0 for it in its)
     if not peak or wall <= 0:
         return None
-    cfg = run.cfg
-    f = sum(flops.chunk_flops(cfg, s, n, last)
+    cfg, arch = run.cfg, manifest.arch(run.cell)
+    f = sum(arch.chunk_flops(cfg, s, n, last)
             for it in its for s, n, last in it.chunks)
-    f += sum(flops.token_flops(cfg, p, True) for it in its
+    f += sum(arch.token_flops(cfg, p, True) for it in its
              for p in it.decodes)
     return 100.0 * f / (wall * peak)
 

@@ -3,16 +3,14 @@
 batching.  It imports nothing of the program and takes nothing the
 program made: its weights come from ``weights.make`` and the seed.
 
-It follows the configuration as it is run (``bench/configs/*.json``):
-token embedding times ``embedding_multiplier``; per layer an RMSNorm
-``x_hat * (1 + w)`` with ``rms_norm_eps``, GQA attention with rotary
-embeddings (the first and second halves of each head rotated as a pair,
-``rope_theta``), scores scaled by ``attention_multiplier``, causal
-softmax, the output projection added to the residual (times
-``residual_multiplier``), then a second norm and the SwiGLU MLP
-``down(silu(gate x) * up x)`` added the same way; a final norm and
-logits against the output head (the embedding where the configuration
-ties them), divided by ``logits_scaling``.
+The forward pass is the architecture module's (``bench/arch/<arch>.py``,
+``forward_hidden``), which builds on the helpers here: ``rms`` (RMSNorm
+``x_hat * (1 + w)``), ``rope`` (the first and second halves of each head
+rotated as a pair) and ``attention`` (causal GQA, optionally inside a
+sliding window).  ``logits`` is the default head: the final hidden
+states against the output head (the embedding where the configuration
+ties them), divided by ``logits_scaling``; a module exports its own
+``logits`` where its head differs.
 
 ``served_gaps`` is the comparison that decides ``correct``: for each
 token a request was served, the gap by which the reference's logit of
@@ -29,15 +27,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import weights
+
 Q_BLOCK = 512
 
 
-def _rms(x, w, eps):
+def rms(x, w, eps):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * (1.0 + w)
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x [S, n, hd]; pos [S]."""
     half = x.shape[-1] // 2
     freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
@@ -47,9 +47,11 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
 
 
-def _attention(q, k, v, scale):
+def attention(q, k, v, scale, window=None):
     """Causal GQA attention, q [S, H, hd], k/v [S, KV, hd], in query
-    blocks so that the scores of one block are all that is held."""
+    blocks so that the scores of one block are all that is held.  With
+    ``window`` a query at position p sees the keys at p - window + 1 ..
+    p (the program's 'local' kind); ``window`` may be traced."""
     s, h, hd = q.shape
     g = h // k.shape[1]
     ke = jnp.repeat(k, g, axis=1)
@@ -61,56 +63,34 @@ def _attention(q, k, v, scale):
         qb = jax.lax.dynamic_slice_in_dim(q, i * Q_BLOCK, Q_BLOCK)
         sc = jnp.einsum("qhd,khd->hqk", qb, ke) * scale
         qpos = i * Q_BLOCK + jnp.arange(Q_BLOCK)
-        sc = jnp.where(kpos[None, None, :] <= qpos[None, :, None], sc,
-                       -jnp.inf)
+        keep = kpos[None, None, :] <= qpos[None, :, None]
+        if window is not None:
+            keep &= qpos[None, :, None] - kpos[None, None, :] < window
+        sc = jnp.where(keep, sc, -jnp.inf)
         p = jax.nn.softmax(sc, axis=-1)
         return jnp.einsum("hqk,khd->qhd", p, ve)
 
     return jax.lax.map(block, jnp.arange(nb)).reshape(s, h, hd)
 
 
-def forward_hidden(cfg: Dict, w: Dict, tokens):
-    """Final normed hidden states [S, d] of one sequence (S a multiple of
-    ``Q_BLOCK``), float32 throughout."""
-    f32 = jnp.float32
-    eps, theta = cfg["rms_norm_eps"], cfg["rope_theta"]
-    h_, kv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                  cfg["head_dim"])
-    res = cfg["residual_multiplier"]
-    s = tokens.shape[0]
-    pos = jnp.arange(s)
-    x = w["embed"][tokens].astype(f32) * cfg["embedding_multiplier"]
-
-    def layer(x, lw):
-        lw = {k: a.astype(f32) for k, a in lw.items()}
-        y = _rms(x, lw["ln1"], eps)
-        q = _rope((y @ lw["wq"]).reshape(s, h_, hd), pos, theta)
-        k = _rope((y @ lw["wk"]).reshape(s, kv, hd), pos, theta)
-        v = (y @ lw["wv"]).reshape(s, kv, hd)
-        a = _attention(q, k, v, cfg["attention_multiplier"])
-        x = x + res * (a.reshape(s, h_ * hd) @ lw["wo"])
-        y = _rms(x, lw["ln2"], eps)
-        x = x + res * ((jax.nn.silu(y @ lw["gate"]) * (y @ lw["up"]))
-                       @ lw["down"])
-        return x, None
-
-    names = ("ln1", "wq", "wk", "wv", "wo", "ln2", "gate", "up", "down")
-    x, _ = jax.lax.scan(layer, x, {k: w[k] for k in names})
-    return _rms(x, w["final_norm"].astype(f32), eps)
+def logits(cfg: Dict, w: Dict, hidden):
+    """Logits [R, V] of final hidden states [R, d] against the output
+    head (the embedding where the configuration ties them)."""
+    head = w["head"] if "head" in w else w["embed"]
+    return hidden @ head.astype(jnp.float32).T / cfg["logits_scaling"]
 
 
 @functools.lru_cache(maxsize=None)
-def _program(cfg_items: Tuple, seq: int, rows: int):
+def _program(arch, cfg_items: Tuple, seq: int, rows: int):
     cfg = dict(cfg_items)
+    head = getattr(arch, "logits", logits)
 
     def run(w, tokens, at, targets):
         with jax.default_matmul_precision("highest"):
-            hf = forward_hidden(cfg, w, tokens)[at]          # [R, d]
-            head = w["head"] if "head" in w else w["embed"]
-            logits = (hf @ head.astype(jnp.float32).T         # [R, V]
-                      / cfg["logits_scaling"])
-            best = jnp.max(logits, axis=-1)
-            chosen = jnp.take_along_axis(logits, targets[:, None], 1)[:, 0]
+            hf = arch.forward_hidden(cfg, w, tokens)[at]    # [R, d]
+            lg = head(cfg, w, hf)                             # [R, V]
+            best = jnp.max(lg, axis=-1)
+            chosen = jnp.take_along_axis(lg, targets[:, None], 1)[:, 0]
             return best, chosen
 
     return jax.jit(run)
@@ -120,7 +100,7 @@ def padded_len(n: int) -> int:
     return -(-n // Q_BLOCK) * Q_BLOCK
 
 
-def served_gaps(cfg: Dict, w: Dict, prompt: np.ndarray,
+def served_gaps(arch, cfg: Dict, w: Dict, prompt: np.ndarray,
                 served: np.ndarray, seq: int, rows: int) -> np.ndarray:
     """Per served token, the reference's best logit minus its logit of the
     served token, at the position that produced it.  ``seq`` and ``rows`` fix
@@ -137,17 +117,16 @@ def served_gaps(cfg: Dict, w: Dict, prompt: np.ndarray,
     at[:n] = len(prompt) - 1 + np.arange(n)
     tg = np.zeros((rows,), np.int32)
     tg[:n] = served
-    items = tuple(sorted(cfg.items(), key=lambda kv: kv[0]))
-    items = tuple((k, v) for k, v in items if not isinstance(v, (dict, list)))
-    best, chosen = _program(items, seq, rows)(w, toks, at, tg)
+    best, chosen = _program(arch, weights.scalars(cfg), seq, rows)(
+        w, toks, at, tg)
     return (np.asarray(best, np.float64) - np.asarray(chosen, np.float64))[:n]
 
 
-def max_gap(cfg: Dict, w: Dict, pairs: Sequence[Tuple[np.ndarray,
-                                                       np.ndarray]],
+def max_gap(arch, cfg: Dict, w: Dict,
+            pairs: Sequence[Tuple[np.ndarray, np.ndarray]],
             seq: int, rows: int) -> Tuple[float, List[float]]:
     """The widest gap over every served token of ``pairs`` (prompt,
     served tokens), and each request's own widest gap."""
-    per = [float(np.max(served_gaps(cfg, w, p, t, seq, rows)))
+    per = [float(np.max(served_gaps(arch, cfg, w, p, t, seq, rows)))
            for p, t in pairs]
     return (max(per) if per else float("nan")), per

@@ -375,11 +375,12 @@ def check(cell: Dict, seed: int, picked: List[Rec]) -> Dict:
     """Score ``picked`` against the reference with the seed's weights."""
     import reference
     import weights
-    geo = cell["geometry"]
-    w = weights.make(cell["config_file"], seed)
+    geo, cfg = cell["geometry"], cell["config_file"]
+    arch = manifest.arch(cell)
+    w = weights.make(arch, cfg, seed)
     seq = reference.padded_len(geo["max_seq_len"])
     rows = cell["mix"]["output"]["max"]
-    gap, per = reference.max_gap(cell["config_file"], w,
+    gap, per = reference.max_gap(arch, cfg, w,
                                  [(r.prompt, r.tokens) for r in picked],
                                  seq, rows)
     del w
@@ -433,8 +434,7 @@ def execute(root: str, workload: str, seed: int, seconds: float,
     if compile_cache:
         enable_cache()
     import serve_adapter
-    sess = serve_adapter.Session(cell["config_file"], cell["geometry"], seed,
-                                 int8=int8, traced=trace)
+    sess = serve_adapter.Session(cell, seed, int8=int8, traced=trace)
     if hook is not None:
         hook(sess)
     tdir = tracer = None

@@ -1,14 +1,15 @@
-"""The one module of the benchmark that touches the program.
+"""The one module of the benchmark that drives the program.
 
-It builds the program's model from a configuration file, puts the
-benchmark's weights (``weights.py``) into the program's parameter tree,
-sizes the page pool, builds ``ServeEngine`` and warms its programs, and
-then drives it through ``ServeEngine.submit`` / ``ServeEngine.step``.
+It builds the program's model from a cell's configuration file through
+the configuration's architecture module (``bench/arch/<arch>.py``:
+``arch_config``, ``to_program``, ``page_bytes``), puts the benchmark's
+weights (``weights.py``) into the program's parameter tree, sizes the
+page pool, builds ``ServeEngine`` and warms its programs, and then
+drives it through ``ServeEngine.submit`` / ``ServeEngine.step``.
 
-The program has no public progress hook yet, so per-request progress is
-read from ``engine.scheduler.lanes`` (prompt tokens prefilled, tokens
-picked) and ``engine.scheduler.queue`` at each ``step()`` return, which
-has synchronised on the token pick.  In traced runs the engine's
+Per-request progress (prompt tokens prefilled, tokens picked) is read
+from ``engine.last_step.lanes``, the record of the latest ``step()``,
+which has synchronised on the token pick.  In traced runs the engine's
 dispatch callables are wrapped from the outside in
 ``jax.profiler.TraceAnnotation`` spans named ``bench.<callable>``
 (``bench.prefill_chunk``, ``bench.decode_paged``, ``bench.pick_paged``,
@@ -19,7 +20,6 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import json
-import math
 import os
 import sys
 from typing import Dict, List, Optional, Tuple
@@ -28,15 +28,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import manifest
 import weights as bench_weights
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if os.path.join(ROOT, "src") not in sys.path:
     sys.path.insert(0, os.path.join(ROOT, "src"))
 
-from repro.configs import get_config  # noqa: E402
 from repro.launch.mesh import make_mesh  # noqa: E402
-from repro.models import param as pm  # noqa: E402
 from repro.models.lm import Model  # noqa: E402
 from repro.serve.api import Request, SamplingParams  # noqa: E402
 from repro.serve.engine import ServeConfig, ServeEngine  # noqa: E402
@@ -51,60 +50,10 @@ MARGIN_BYTES = 512 * 2**20
 MARGIN_SHARE = 0.02
 
 
-def arch_config(cfg: Dict):
-    """The program's ``ArchConfig`` for a configuration file: the
-    registry's entry with the file's sizes, dtypes and constants."""
-    base = get_config(cfg["registry"])
-    arch = dataclasses.replace(
-        base, n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        norm_eps=cfg["rms_norm_eps"], rope_theta=cfg["rope_theta"],
-        param_dtype=cfg["param_dtype"], compute_dtype=cfg["compute_dtype"],
-        block_pattern=("global",), gated_mlp=True, moe=False,
-        tie_embeddings=cfg["tie_word_embeddings"], attn_softcap=None,
-        final_softcap=None, rope_theta_global=None)
-    # constants the program fixes in code: the file must state them as run
-    want = {"embedding_multiplier": math.sqrt(arch.d_model),
-            "attention_multiplier": arch.hd ** -0.5,
-            "residual_multiplier": 1.0, "logits_scaling": 1.0}
-    for k, v in want.items():
-        if not math.isclose(cfg[k], v, rel_tol=1e-12):
-            raise ValueError(f"{cfg['name']}: {k} is {cfg[k]}, the program "
-                             f"runs {v}")
-    return arch
-
-
-def _to_program(model: Model, w: Dict) -> Dict:
-    """Traceable: the plain layout of ``weights.py`` -> the program's
-    parameter tree (packed QKV, one-shard MLP weights, padded vocab)."""
-    cfg = model.cfg
-    defs = model.param_defs()
-    vp = cfg.padded_vocab()
-    qkv = pm.pack_views(defs["groups"]["b0"]["attn"]["wqkv"],
-                        {"wq": w["wq"], "wk": w["wk"], "wv": w["wv"]})
-    out = {
-        "embed": jnp.pad(w["embed"], ((0, vp - cfg.vocab), (0, 0))),
-        "final_norm": w["final_norm"],
-        "groups": {"b0": {
-            "ln1": w["ln1"],
-            "attn": {"wqkv": qkv, "wo": w["wo"]},
-            "ln2": w["ln2"],
-            "ffn": {"up": w["up"][:, None], "gate": w["gate"][:, None],
-                    "down": w["down"][:, None]},
-        }},
-        "tail": {},
-    }
-    if "head" in w:
-        out["head"] = jnp.pad(w["head"], ((0, vp - cfg.vocab), (0, 0)))
-    return out
-
-
-def program_params(model: Model, cfg: Dict, seed: int):
+def program_params(model: Model, arch, cfg: Dict, seed: int):
     """The seed's weights in the program's tree, in one jitted call."""
     fn = jax.jit(lambda lo, hi: bench_weights.draw(
-        cfg, lo, hi, lambda w: _to_program(model, w)))
+        arch, cfg, lo, hi, lambda w: arch.to_program(model, w)))
     lo, hi = bench_weights.seed_words(seed)
     got = jax.eval_shape(fn, lo, hi)
     want = model.abstract_params()
@@ -139,12 +88,6 @@ def _extra_bytes(compiled) -> int:
     ma = compiled.memory_analysis()
     return (ma.temp_size_in_bytes + ma.output_size_in_bytes
             - ma.alias_size_in_bytes)
-
-
-def page_bytes(cfg: Dict, page_size: int) -> int:
-    """Device bytes of one page: K and V of every layer, bf16."""
-    return (cfg["num_hidden_layers"] * 2 * page_size
-            * cfg["num_key_value_heads"] * cfg["head_dim"] * 2)
 
 
 @dataclasses.dataclass
@@ -207,7 +150,7 @@ def _compiles(lowered, store: _Store):
     return out
 
 
-def size_pool(model: Model, cfg: Dict, scfg: ServeConfig,
+def size_pool(model: Model, arch, cfg: Dict, scfg: ServeConfig,
               stats: Optional[Dict] = None) -> Sizing:
     """Pages that fill the memory left after the weights: the step
     programs' needs beside their arguments grow with the pool, so they are
@@ -220,7 +163,7 @@ def size_pool(model: Model, cfg: Dict, scfg: ServeConfig,
     the full tables.  ``stats`` stands in for ``memory_stats`` in tests."""
     ppl = -(-scfg.max_seq_len // scfg.page_size)
     full = scfg.n_lanes * ppl
-    pb = page_bytes(cfg, scfg.page_size)
+    pb = arch.page_bytes(cfg, scfg.page_size)
     stats = stats or jax.devices()[0].memory_stats()
     if not stats or "bytes_limit" not in stats:
         return Sizing(full, ppl, pb)
@@ -258,25 +201,27 @@ def size_pool(model: Model, cfg: Dict, scfg: ServeConfig,
 
 
 class Session:
-    """One engine serving one cell.  ``step()`` returns what changed."""
+    """One engine serving one cell (``manifest.cell``).  ``step()``
+    returns what changed."""
 
-    def __init__(self, cfg: Dict, geometry: Dict, seed: int, *,
-                 int8: bool = False, traced: bool = False):
+    def __init__(self, cell: Dict, seed: int, *, int8: bool = False,
+                 traced: bool = False):
+        cfg, geometry = cell["config_file"], cell["geometry"]
+        arch = manifest.arch(cell)
         self.cfg = cfg
-        self.model = Model(arch_config(cfg), make_mesh(1, 1))
+        self.model = Model(arch.arch_config(cfg), make_mesh(1, 1))
         if not self.model.supports_paged_serving:
             raise RuntimeError(f"{cfg['name']}: no paged serving path")
         scfg = ServeConfig(int8=int8, n_lanes=geometry["n_lanes"],
                            page_size=geometry["page_size"],
                            prefill_chunk=geometry["prefill_chunk"],
                            max_seq_len=geometry["max_seq_len"])
-        self.engine = ServeEngine(self.model,
-                                  program_params(self.model, cfg, seed),
-                                  scfg)
+        self.engine = ServeEngine(
+            self.model, program_params(self.model, arch, cfg, seed), scfg)
         jax.block_until_ready(self.engine.params)
-        self.sizing = size_pool(self.model, cfg, scfg)
+        self.sizing = size_pool(self.model, arch, cfg, scfg)
         scfg.n_pages = self.sizing.n_pages
-        self.sched = self.engine.scheduler     # allocates the page pool
+        self.engine.scheduler                  # allocates the page pool
         self._warm()
         self.traced = traced
         if traced:
@@ -315,8 +260,9 @@ class Session:
     def progress(self) -> Dict[int, Tuple[int, int, int]]:
         """Request id -> (prompt tokens prefilled, tokens picked, prompt
         length) of every request in a lane."""
-        return {a.req.id: (a.n_prefilled, len(a.tokens), len(a.req.tokens))
-                for a in self.sched.lanes if a is not None}
+        rec = self.engine.last_step
+        return {rid: (pre, picked, plen)
+                for rid, pre, picked, plen in (rec.lanes if rec else ())}
 
     def step(self) -> List[Tuple[int, str, np.ndarray]]:
         """One scheduler iteration; returns (id, status, tokens) of the
@@ -327,7 +273,6 @@ class Session:
 
     def close(self) -> None:
         """Free the engine's weights, pool and programs' buffers."""
-        self.sched = None
         self.engine = None
         self.model = None
 

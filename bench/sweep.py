@@ -37,8 +37,7 @@ def main(argv=None) -> int:
     run.device_info(cell["chips"], True)
     run.enable_cache()
     import serve_adapter
-    sess = serve_adapter.Session(cell["config_file"], cell["geometry"],
-                                 args.seed)
+    sess = serve_adapter.Session(cell, args.seed)
     rates = [float(r) for r in args.rates.split(",")]
     for i, rate in enumerate(rates):
         c = dict(cell, rate_per_s=rate, drain_s=args.drain)
